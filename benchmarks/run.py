@@ -331,6 +331,20 @@ def engine_bench(fast: bool):
     import subprocess
     import sys
 
+    import jax
+
+    # the sharded and steady-state legs start child processes that
+    # initialise jax on forced virtual CPU devices; on an accelerator this
+    # process already holds the chip, and such a child would fail or hang
+    # on the accelerator library's lock
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"--suite engine runs its mesh legs in child processes on "
+            f"virtual CPU devices and cannot share this process's "
+            f"{jax.default_backend()} devices: run it with "
+            f"JAX_PLATFORMS=cpu (on the chip, `python chip_smoke.py "
+            f"--four-chips` drives the engine mesh in one process)")
+
     from repro.core import engine as engine_mod
     from repro.core.batch import estimate_many
     from repro.core.estimator import estimate

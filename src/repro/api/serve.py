@@ -27,7 +27,9 @@ Control lines: ``{"cmd": "stats"}`` (session counters plus an
 how much sample-stream sharing the standing queries achieve), ``{"cmd":
 "health"}`` (liveness probe, answered IMMEDIATELY without draining the
 coalescing window: mode, pending/served counts, process-wide resilience
-counters, the same ``engine`` block, an ``obs`` telemetry block, and in
+counters, the same ``engine`` block, an ``obs`` telemetry block, a
+``device`` block — ``platform``, ``kind`` and ``count`` as JAX reports
+them, plus ``peak_bytes`` where the backend reports memory — and in
 stream mode the current epoch + WAL position), ``{"cmd": "quit"}``
 (drain + exit; EOF does the same).
 
@@ -206,6 +208,17 @@ def _stats(session: Session | None, stream=None) -> dict:
     return d
 
 
+def device_block() -> dict:
+    """The ``device`` block of the ``health`` verb: the devices this
+    process computes on, as JAX reports them, and the first device's
+    peak memory in bytes where the backend reports one (else ``None``)."""
+    import jax
+    devs = jax.devices()
+    mem = devs[0].memory_stats() or {}
+    return dict(platform=devs[0].platform, kind=devs[0].device_kind,
+                count=len(devs), peak_bytes=mem.get("peak_bytes_in_use"))
+
+
 def _health(stream, n_pending: int, served: int) -> dict:
     """The ``health`` verb's payload: liveness + resilience counters.
 
@@ -216,7 +229,8 @@ def _health(stream, n_pending: int, served: int) -> dict:
              mode="plain" if stream is None else "stream",
              pending=n_pending, served=served,
              resilience=RSTATS.as_dict(),
-             engine=_engine_stats(), obs=obs.summary())
+             engine=_engine_stats(), obs=obs.summary(),
+             device=device_block())
     if stream is not None:
         st = stream.store
         d.update(epoch=st.epoch, buffered=st.buffered)

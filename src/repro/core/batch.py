@@ -26,6 +26,7 @@ fused dispatch or mesh shard executes it (engine determinism contract).
 """
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Iterable, Sequence
 
@@ -88,35 +89,59 @@ class BatchPlanner:
     def _wd(self, delta: int) -> int:
         return int(delta) if self.use_c3 else int(self.g.time_span) + 1
 
-    def weights_for(self, tree: SpanningTree, delta: int) -> Weights:
+    def _key(self, tree: SpanningTree, delta: int) -> tuple:
         # keyed on the STRUCTURAL signature, not the tree object: the
         # weight DP reads only signature fields, so trees of *different
         # motifs* sharing a signature resolve to one Weights object —
         # which is exactly the identity the engine's tree-cohort
         # grouping keys on (shared object => shared sample stream)
-        key = (tree_signature(tree), int(delta), self._wd(delta),
-               self.use_c2, self.backend)
-        hit = key in self._weights
-        if hit:
+        return (tree_signature(tree), int(delta), self._wd(delta),
+                self.use_c2, self.backend)
+
+    def _preprocess(self, tree: SpanningTree, delta: int) -> Weights:
+        return preprocess(self.g, tree, delta, dev=self.dev,
+                          use_c2=self.use_c2, use_c3=self.use_c3,
+                          backend=self.backend)
+
+    def weights_for(self, tree: SpanningTree, delta: int) -> Weights:
+        key = self._key(tree, delta)
+        if key in self._weights:
             self.preprocess_hits += 1
         else:
             self.preprocess_calls += 1
-            self._weights[key] = preprocess(
-                self.g, tree, delta, dev=self.dev, use_c2=self.use_c2,
-                use_c3=self.use_c3, backend=self.backend)
+            self._weights[key] = self._preprocess(tree, delta)
         return self._weights[key]
 
     def plan(self, motif: TemporalMotif, delta: int
              ) -> tuple[SpanningTree, Weights]:
-        """Min-W tree + its Weights for (motif, delta), cached."""
+        """Min-W tree + its Weights for (motif, delta), cached.
+
+        The uncached candidates preprocess in concurrent threads: each
+        is one compile on the host (tens of seconds on a TPU at
+        realistic sizes) and one DP on the device.  The compiles run
+        side by side; the device still runs the DPs one at a time, so
+        peak device memory is that of sequential DPs.  The ranking below
+        reads the candidates in order, so the choice is the sequential
+        one."""
         pkey = (motif, int(delta))
         if pkey in self._plans:
             return self._plans[pkey]
         cands = candidate_trees(motif, n_candidates=self.n_candidates,
                                 roots_per_tree=self.roots_per_tree)
+        todo: dict = {}
+        for tree in cands:
+            key = self._key(tree, delta)
+            if key not in self._weights:
+                todo.setdefault(key, tree)
+        with ThreadPoolExecutor(max(1, len(todo))) as pool:
+            done = dict(zip(todo, pool.map(
+                lambda tree: self._preprocess(tree, delta), todo.values())))
+        self._weights.update(done)
+        self.preprocess_calls += len(done)
+        self.preprocess_hits += len(cands) - len(done)
         best = None
         for tree in cands:
-            w = self.weights_for(tree, delta)
+            w = self._weights[self._key(tree, delta)]
             Wt = int(w.W_total)
             if best is None or Wt < best[0]:
                 best = (Wt, tree, w)

@@ -19,6 +19,13 @@ import jax.numpy as jnp
 ITERS = 40
 
 
+def converge_iters(n: int) -> int:
+    """A fixed trip count that converges any bisection over at most ``n``
+    positions: each step at least halves the segment, so ``bit_length(n)``
+    steps do; one more and a floor of 8 keep every caller on one count."""
+    return max(8, int(n).bit_length() + 1)
+
+
 def seg_lower_bound(vals: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray,
                     target: jnp.ndarray, iters: int = ITERS) -> jnp.ndarray:
     """Smallest ``p in [lo, hi]`` with ``vals[p] >= target`` (``hi`` if none).

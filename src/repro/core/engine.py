@@ -103,7 +103,6 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from ..dist.collectives import folded_axis_index  # noqa: E402
 from ..dist.sharding import data_axes, n_data  # noqa: E402
-from ..util import get_shard_map  # noqa: E402
 from .estimator import _ACC_KEYS, EstimateResult, unbias_estimate  # noqa: E402
 from .motif import TemporalMotif  # noqa: E402
 from .sampler import (WITNESS_SENTINEL, make_batched_sample_fn,  # noqa: E402
@@ -193,9 +192,8 @@ def make_engine_window_fn(trees, chunk: int, Lmax: int = 16,
             acc, _ = jax.lax.scan(step, acc0, jnp.arange(slots))
             return jax.lax.psum(acc, axes)
 
-        sm = get_shard_map()(body, mesh=mesh,
-                             in_specs=(P(), P(), P(), P()),
-                             out_specs=P(), check_rep=False)
+        sm = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(), P(), P()),
+                           out_specs=P(), check_vma=False)
         return sm(dev, wts, base_keys, j0)
 
     return jax.jit(window, static_argnames=("n",))

@@ -110,20 +110,17 @@ class TemporalGraph:
             n = int(max(src.max(), dst.max())) + 1
         t = t - t.min()
 
-        # enforce unique (u, v, t) tuples (paper's input model)
-        tup = np.stack([src.astype(np.int64), dst.astype(np.int64), t], axis=1)
-        uniq = np.unique(tup, axis=0)
-        if len(uniq) != m:
-            keep_idx = np.unique(
-                src.astype(np.int64) * (n * (t.max() + 1))
-                + dst.astype(np.int64) * (t.max() + 1) + t,
-                return_index=True)[1]
-            src, dst, t = src[keep_idx], dst[keep_idx], t[keep_idx]
-            m = len(src)
-
-        # global sort by (t, src, dst) — gives stable edge ids
+        # global sort by (t, src, dst) — gives stable edge ids; repeated
+        # (u, v, t) tuples are adjacent in this order
         order = np.lexsort((dst, src, t))
         src, dst, t = src[order], dst[order], t[order]
+        # enforce unique (u, v, t) tuples (paper's input model): keep the
+        # first of each run
+        keep = np.r_[True, (t[1:] != t[:-1]) | (src[1:] != src[:-1])
+                     | (dst[1:] != dst[:-1])]
+        if not keep.all():
+            src, dst, t = src[keep], dst[keep], t[keep]
+            m = len(src)
         eid = np.arange(m, dtype=np.int32)
 
         def csr(group: np.ndarray, size: int):

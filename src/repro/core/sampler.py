@@ -28,7 +28,8 @@ ensure_x64()
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from .bisect import monotone_find, seg_lower_bound, seg_upper_bound  # noqa: E402
+from .bisect import (converge_iters, monotone_find,  # noqa: E402
+                     seg_lower_bound, seg_upper_bound)
 from .spanning_tree import BEFORE, OUT, SpanningTree  # noqa: E402
 
 
@@ -36,7 +37,7 @@ def bisect_iters(m: int) -> int:
     """Adaptive bisection depth: ceil(log2(m))+1 covers any segment of an
     m-edge graph (vs a conservative fixed 40 — §Perf C1).
     ``REPRO_BISECT_ITERS`` overrides (A/B tuning)."""
-    return get_knob("REPRO_BISECT_ITERS") or max(8, int(m).bit_length() + 1)
+    return get_knob("REPRO_BISECT_ITERS") or converge_iters(m)
 
 
 def sampler_backend(backend: str | None = None) -> str:
@@ -46,7 +47,8 @@ def sampler_backend(backend: str | None = None) -> str:
     "pallas" — the kernels/tree_sampler fused kernel: the whole per-sample
                pipeline (window draw, center edge, every child bisection)
                in ONE ``pallas_call`` over VMEM-resident CSR times and f32
-               prefix sums.  Bit-identical to "xla" while every weight
+               prefix sums.  CPU-interpret only: the TPU compiler refuses
+               it until ROADMAP S2.  Bit-identical to "xla" while every weight
                prefix stays inside f32's exact-integer range (< 2^24);
                callers gate on ``tree_sampler.ops.pallas_sampler_eligible``
                and fall back to "xla" otherwise (``estimate`` does this).
@@ -245,7 +247,7 @@ def _make_sample_fn_xla(tree: SpanningTree, K: int):
         # trip count from the STATIC window-array length (>= the traced
         # real q; extra iterations are converged no-ops) — wts.q itself
         # is traced so epoch snapshots never retrace on window count
-        itq = max(8, wts.q_pad.bit_length() + 1)
+        itq = converge_iters(wts.q_pad)
         win = seg_upper_bound(wts.ps_win, jnp.zeros((K,), jnp.int64),
                               jnp.full((K,), wts.q, jnp.int64), x,
                               iters=itq) - 1

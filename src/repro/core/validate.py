@@ -35,7 +35,8 @@ ensure_x64()
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from .bisect import seg_lower_bound, seg_upper_bound  # noqa: E402
+from .bisect import (converge_iters, seg_lower_bound,  # noqa: E402
+                     seg_upper_bound)
 from .motif import TemporalMotif  # noqa: E402
 from .spanning_tree import SpanningTree  # noqa: E402
 
@@ -68,7 +69,7 @@ def make_count_fn(tree: SpanningTree, K: int, Lmax: int = 16):
         return local_of_rank[c[0]] if c else None
 
     def fn(dev, wts, samples):
-        it = max(8, int(dev["t"].shape[0]).bit_length() + 1)
+        it = converge_iters(dev["t"].shape[0])
         E = samples["edges"]          # [K, S]
         phi_v = samples["phi_v"]      # [K, nv]
         t = dev["t"]

@@ -53,7 +53,8 @@ def depsum_backend(backend: str | None = None) -> str:
 
     "xla"    — exact int64 bisect + prefix gathers (default);
     "pallas" — the kernels/interval_weight fused kernel on f32-cast
-               prefixes (interpret mode off-TPU).  Callers must check the
+               prefixes (CPU-interpret only: the TPU compiler refuses
+               it until ROADMAP S2).  Callers must check the
                returned ``exact`` flag and fall back when counts overflow
                f32's exact-integer range (``preprocess`` does this).
     """
@@ -138,6 +139,15 @@ def _excl(x):
     return jnp.concatenate([jnp.zeros((1,), x.dtype), jnp.cumsum(x)])
 
 
+def _converge_depth(ptr):
+    """Bisection steps that converge in every segment of the CSR ``ptr``:
+    each step at least halves a segment, so ``bit_length`` of the longest
+    one suffices and further steps change nothing.  Read off the graph
+    on the device (a traced trip count), so no graph recompiles the DP."""
+    longest = jnp.max(ptr[1:] - ptr[:-1], initial=0).astype(jnp.int64)
+    return 64 - jax.lax.clz(longest)
+
+
 # ---------------------------------------------------------------------------
 # the vectorized DP
 # ---------------------------------------------------------------------------
@@ -182,6 +192,7 @@ def make_preprocess_fn(tree: SpanningTree, use_c2: bool = True,
             ptr, csr_t = dev["out_ptr"], dev["out_t"]
         else:
             ptr, csr_t = dev["in_ptr"], dev["in_t"]
+        it = _converge_depth(ptr)
         p0 = ptr[meet]
         p1 = ptr[meet + 1]
 
@@ -199,9 +210,10 @@ def make_preprocess_fn(tree: SpanningTree, use_c2: bool = True,
             from ..kernels.interval_weight.ops import interval_weight
             lam = interval_weight(csr_t, pso, psp, p0, p1, tlo, thi, brk)
         else:
-            plo = seg_lower_bound(csr_t, p0, p1, tlo)
-            phi = seg_upper_bound(csr_t, p0, p1, thi)
-            pmid = jnp.clip(seg_lower_bound(csr_t, p0, p1, brk), plo, phi)
+            plo = seg_lower_bound(csr_t, p0, p1, tlo, iters=it)
+            phi = seg_upper_bound(csr_t, p0, p1, thi, iters=it)
+            pmid = jnp.clip(seg_lower_bound(csr_t, p0, p1, brk, iters=it),
+                            plo, phi)
             lam = (pso[pmid] - pso[plo]) + (psp[phi] - psp[pmid])
         if not use_c2:
             return lam
@@ -221,9 +233,11 @@ def make_preprocess_fn(tree: SpanningTree, use_c2: bool = True,
             from ..kernels.interval_weight.ops import interval_weight
             el = interval_weight(pt, ppo, ppp, q0, q1, tlo, thi, brk)
         else:
-            qlo = seg_lower_bound(pt, q0, q1, tlo)
-            qhi = seg_upper_bound(pt, q0, q1, thi)
-            qmid = jnp.clip(seg_lower_bound(pt, q0, q1, brk), qlo, qhi)
+            itp = _converge_depth(dev["pair_ptr"])
+            qlo = seg_lower_bound(pt, q0, q1, tlo, iters=itp)
+            qhi = seg_upper_bound(pt, q0, q1, thi, iters=itp)
+            qmid = jnp.clip(seg_lower_bound(pt, q0, q1, brk, iters=itp),
+                            qlo, qhi)
             el = (ppo[qmid] - ppo[qlo]) + (ppp[qhi] - ppp[qmid])
         return lam - el
 
@@ -334,6 +348,7 @@ def make_preprocess_fn(tree: SpanningTree, use_c2: bool = True,
         out["W_total"] = out["ps_win"][-1]
         return out
 
+    fn.core = core_j     # the heavy [S, m] program (off-chip compile tests)
     return fn
 
 

@@ -13,8 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..util import get_shard_map
-
 
 def folded_axis_index(mesh, axes) -> jnp.ndarray:
     """Row-major linear shard index over ``axes`` (inside shard_map).
@@ -75,7 +73,6 @@ def sharded_embedding_lookup(table: jnp.ndarray, idx: jnp.ndarray, mesh,
         out = jnp.where(here[..., None], tab[loc], 0)
         return jax.lax.psum(out, axis)
 
-    fn = get_shard_map()(local, mesh=mesh,
-                         in_specs=(P(axis, None), P()),
-                         out_specs=P(), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(axis, None), P()),
+                       out_specs=P(), check_vma=False)
     return fn(table, idx)

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..models import gnn
-from ..util import get_shard_map
 from .sharding import data_axes
 
 
@@ -76,8 +75,8 @@ def make_sharded_gnn_loss(cfg, mesh, batch):
         # cotangent psum — exact gradients, no overcount.
         return jax.lax.pmean(loss, all_axes)
 
-    fn = get_shard_map()(local_loss, mesh=mesh, in_specs=(P(), specs),
-                         out_specs=P(), check_rep=False)
+    fn = jax.shard_map(local_loss, mesh=mesh, in_specs=(P(), specs),
+                       out_specs=P(), check_vma=False)
 
     def loss_fn(params, b):
         return fn(params, b)

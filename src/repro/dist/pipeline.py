@@ -13,8 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..util import get_shard_map
-
 
 def gpipe_forward(stage_fn, stage_params: jnp.ndarray, xs: jnp.ndarray,
                   mesh, axis: str = "pod") -> jnp.ndarray:
@@ -59,7 +57,6 @@ def gpipe_forward(stage_fn, stage_params: jnp.ndarray, xs: jnp.ndarray,
         return jax.lax.psum(outs * keep, axis)
 
     w_spec = P(axis, *([None] * (stage_params.ndim - 1)))
-    fn = get_shard_map()(run, mesh=mesh,
-                         in_specs=(w_spec, P()),
-                         out_specs=P(), check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh, in_specs=(w_spec, P()),
+                       out_specs=P(), check_vma=False)
     return fn(stage_params, xs)

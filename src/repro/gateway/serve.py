@@ -227,7 +227,10 @@ class _Gateway:
                 wal=bool(obj.get("wal")))
             d = dict(ok=True, cmd="open_tenant", tenant=tenant.name,
                      mode=tenant.mode, pool_size=len(self.state.tenants))
-            if tenant.mode == "stream":
+            if tenant.mode == "graph":
+                g = tenant.session.g
+                d.update(n=g.n, m=g.m)
+            else:
                 st = tenant.stream.store
                 # a WAL-recovered tenant resumes mid-history: epoch > 0
                 # or edges already buffered at open
@@ -355,6 +358,7 @@ class _Gateway:
         self.emit(dict(**head, ok=False, **payload))
 
     def health(self) -> dict:
+        from ..api.serve import device_block
         s = self.sched.stats
         return dict(
             ok=True, cmd="health", mode="gateway", served=self.served,
@@ -366,7 +370,7 @@ class _Gateway:
                            quota=self.sched.quota),
             evictions=self.state.evictions,
             resilience=RSTATS.as_dict(), engine=self._engine_block(),
-            obs=obs.summary())
+            obs=obs.summary(), device=device_block())
 
     def stats(self) -> dict:
         d = self.health()

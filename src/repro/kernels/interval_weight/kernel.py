@@ -15,8 +15,13 @@ array and both prefix arrays are VMEM-resident (one 2^20-edge time shard
 launcher chunks the graph by time range — which TIMEST's Constraint-3
 windows already do); queries stream through in ``bq`` blocks; the
 bisection is branchless fixed-trip (trip count adapts to the shard size,
-``max(8, m.bit_length() + 1)``) and fully vectorized across the block, so each
-iteration is one VMEM gather + compare + select on an 8x128-lane vector.
+``core.bisect.converge_iters(m)``) and fully vectorized across the block,
+so each iteration is one VMEM gather + compare + select on an 8x128-lane vector.
+
+Status: CPU-interpret only until ROADMAP S2.  Compiled for a v5e at
+m = 65536 the TPU compiler refuses it (``NotImplementedError: Only 2D
+gather is supported``: the 1-D ``jnp.take`` gathers here and in
+``kernels/bisect.py``).  The default ``xla`` dep-sum is the chip path.
 
 Weights dtype: f32 here (counts < 2^24 exact). The estimator's exact-int64
 path stays in XLA; the f32-rebased two-level scheme for larger counts is
@@ -30,6 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...core.bisect import converge_iters
 from ..bisect import seg_bisect as _bisect
 from ..padding import pad_block
 
@@ -71,7 +77,7 @@ def interval_weight_call(csr_t, ps_own, ps_prev, p0, p1, tlo, thi, brk, *,
     # trip count from the shard size alone — deliberately NOT the
     # REPRO_BISECT_ITERS sampler A/B knob, which must never be able to
     # under-iterate the weight DP (it would corrupt dep-sums silently)
-    iters = max(8, m.bit_length() + 1)
+    iters = converge_iters(m)
     out = pl.pallas_call(
         functools.partial(_iw_kernel, iters=iters),
         grid=grid,

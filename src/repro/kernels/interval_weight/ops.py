@@ -1,4 +1,7 @@
-"""jit'd wrapper: interpret auto-select (padding lives in the kernel call)."""
+"""jit'd wrapper: interpret auto-select (padding lives in the kernel call).
+
+CPU-interpret only until ROADMAP S2: the TPU compiler refuses the kernel
+(see ``kernel.py``)."""
 from __future__ import annotations
 
 from functools import partial

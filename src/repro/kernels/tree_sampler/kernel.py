@@ -23,6 +23,12 @@ bisections make is exact, so the kernel's trajectory — and therefore the
 sampled edge ids — is **bit-identical** to the exact-int64 XLA path
 (``ops.pallas_sampler_eligible`` gates this; ``estimate`` falls back).
 
+Status: CPU-interpret only until ROADMAP S2.  Compiled for a v5e
+(m = 65536, M5-3) Mosaic fails while lowering the first bisection
+(``kernels/bisect.py``) with a ``RecursionError`` under x64, and the
+kernel does uint64 arithmetic in ``randint_from_bits``, which the TPU
+has no native units for.  The default ``xla`` backend is the chip path.
+
 Randomness contract: the kernel draws nothing itself.  The window/center
 target ``x`` is precomputed outside (its span ``W`` is known on the XLA
 side) and each child receives the two raw 64-bit draws of

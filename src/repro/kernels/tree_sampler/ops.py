@@ -11,8 +11,12 @@ use to fall back to XLA outside the kernel's exactness/capacity envelope:
   (< 2^24) — beyond it the f32 bisection comparisons would round;
 * window-shifted time bounds must fit int32;
 * the kernel-resident structure must fit the VMEM budget
-  (``REPRO_SAMPLER_VMEM_MB``, default 192 — generous for interpret mode;
-  set ~14 for a real single-core TPU deployment).
+  (``REPRO_SAMPLER_VMEM_MB``, default 192 — sized for interpret mode; a
+  v5e's compiler caps VMEM at 128 MiB).
+
+CPU-interpret only until ROADMAP S2: off a TPU ``interpret`` defaults to
+True, and on a TPU the compiler refuses the kernel (Mosaic lowering of
+its first bisection fails under x64), so no chip has run it.
 
 Structural-fields-only contract: this module (like the XLA sampler it
 mirrors) reads ONLY the fields captured by
@@ -35,6 +39,7 @@ ensure_x64()
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from ...core.bisect import converge_iters  # noqa: E402
 from ...core.sampler import bisect_iters  # noqa: E402
 from ...knobs import get_knob  # noqa: E402
 from ...core.spanning_tree import SpanningTree  # noqa: E402
@@ -165,7 +170,7 @@ def make_pallas_sample_fn(tree: SpanningTree, K: int, *, bk: int | None = None,
         it = bisect_iters(m)
         # static shape-derived trip count (wts.q is traced); == the old
         # q-derived count on unpadded graphs
-        itq = max(8, wts.q_pad.bit_length() + 1)
+        itq = converge_iters(wts.q_pad)
         x, uhi, ulo = prepare_draws(tree, wts, key, K)
         arrays = _device_prep(dev, wts)
         edges32, win32 = tree_sampler_call(

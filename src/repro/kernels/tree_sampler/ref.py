@@ -9,7 +9,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from ...core.bisect import monotone_find, seg_lower_bound, seg_upper_bound
+from ...core.bisect import (converge_iters, monotone_find, seg_lower_bound,
+                            seg_upper_bound)
 from ...core.sampler import _two_piece, bisect_iters
 from ...core.spanning_tree import BEFORE, OUT, SpanningTree
 from .kernel import randint_from_bits
@@ -26,7 +27,7 @@ def tree_sampler_ref(tree: SpanningTree, dev, wts, x, uhi, ulo):
     r = tree.root
     K = x.shape[0]
 
-    itq = max(8, wts.q_pad.bit_length() + 1)
+    itq = converge_iters(wts.q_pad)
     win = seg_upper_bound(wts.ps_win, jnp.zeros((K,), jnp.int64),
                           jnp.full((K,), wts.q, jnp.int64), x,
                           iters=itq) - 1

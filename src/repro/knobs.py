@@ -49,11 +49,14 @@ class Knob:
 KNOBS: dict[str, Knob] = {k.name: k for k in (
     Knob("REPRO_SAMPLER_BACKEND", "xla", str,
          "sampling path: XLA gather chain or the fused kernels/"
-         "tree_sampler pallas kernel (bit-identical)",
+         "tree_sampler pallas kernel (bit-identical; CPU-interpret only — "
+         "the TPU compiler refuses the kernel until ROADMAP S2)",
          choices=("xla", "pallas"), result_affecting=True),
     Knob("REPRO_DEPSUM_BACKEND", "xla", str,
          "weight-preprocess dep-sum inner loop: exact int64 XLA or the "
-         "kernels/interval_weight pallas kernel (f32-exact audited)",
+         "kernels/interval_weight pallas kernel (f32-exact audited; "
+         "CPU-interpret only — the TPU compiler refuses the kernel until "
+         "ROADMAP S2)",
          choices=("xla", "pallas"), result_affecting=True),
     Knob("REPRO_ENGINE_CACHE", 32, int,
          "bounded LRU capacity for compiled engine window programs"),
@@ -62,9 +65,11 @@ KNOBS: dict[str, Knob] = {k.name: k for k in (
          "ceil(log2(m))+1; A/B tuning only — converged extra iterations "
          "are no-ops, so results never change)"),
     Knob("REPRO_SAMPLER_VMEM_MB", 192, int,
-         "VMEM budget (MiB) for the fused tree_sampler kernel's "
-         "resident CSR/prefix structure; ineligible jobs fall back to "
-         "xla (~14 MiB/core on real TPU hardware)"),
+         "VMEM budget (MiB) the eligibility gate allows the fused "
+         "tree_sampler kernel's resident CSR/prefix structure; ineligible "
+         "jobs fall back to xla.  Sized for interpret mode: a v5e's "
+         "compiler caps VMEM at 128 MiB, and no budget is known to work "
+         "on a chip until the kernel compiles there (ROADMAP S2)"),
     Knob("REPRO_SAMPLER_BLOCK", 1024, int,
          "sample-axis block width of the fused tree_sampler kernel"),
     Knob("REPRO_OBS", "off", str,

@@ -61,7 +61,12 @@ interval-weight kernel (exact-int64 XLA fallback on overflow);
 ``--sampler-backend pallas`` routes sampling through the fused
 kernels/tree_sampler kernel (one ``pallas_call`` per chunk, bit-identical
 samples; ineligible jobs fall back per job without downgrading fused
-siblings).
+siblings).  Both kernels run in CPU interpret mode only: the TPU compiler
+refuses them until ROADMAP S2, so the default ``xla`` backends are the
+chip path.
+
+The persistent compile cache lives where ``JAX_COMPILATION_CACHE_DIR``
+says, else at ``<checkout>/.jax_cache`` (``launch.compile_cache``).
 """
 from __future__ import annotations
 
@@ -101,6 +106,10 @@ def main() -> None:
                     help="window, or comma list for batched serving")
     ap.add_argument("--k", type=int, default=1 << 18)
     ap.add_argument("--chunk", type=int, default=1 << 13)
+    ap.add_argument("--checkpoint-every", type=int, default=64,
+                    help="chunks per engine window: the granularity of "
+                         "dispatches, checkpoints, progress and batch-means "
+                         "RSE (counts do not depend on it)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--mesh", default=None,
@@ -198,6 +207,8 @@ def main() -> None:
     if args.profile_dir is not None and not args.serve:
         ap.error("--profile-dir requires --serve (the 'profile' verb "
                  "arms the profiler over the wire)")
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
     if args.devices:
         from .mesh import force_host_device_count
         force_host_device_count(args.devices)
@@ -226,15 +237,19 @@ def main() -> None:
         import sys
 
         from ..api import EstimateConfig
+        from ..api.serve import device_block
         from ..gateway import gateway_serve_loop
         cfg = EstimateConfig(chunk=args.chunk, seed=args.seed,
+                             checkpoint_every=args.checkpoint_every,
                              coalesce_window_s=args.coalesce_window,
                              coalesce_max_requests=args.coalesce_max,
                              sampler_backend=args.sampler_backend,
                              depsum_backend=args.depsum_backend)
+        dv = device_block()
         print(f"serving GATEWAY  max_tenants={args.max_tenants}  "
               f"quota={args.tenant_quota}  wal_dir={args.wal_dir}  "
-              f"mesh={mesh.shape if mesh is not None else None}",
+              f"mesh={dict(mesh.shape) if mesh is not None else None}  "
+              f"device={dv['platform']}:{dv['kind']}x{dv['count']}",
               file=sys.stderr, flush=True)
         served = gateway_serve_loop(cfg, max_tenants=args.max_tenants,
                                     quota=args.tenant_quota,
@@ -249,6 +264,7 @@ def main() -> None:
         from ..api import EstimateConfig, serve_loop
         from ..stream import StreamingSession
         cfg = EstimateConfig(chunk=args.chunk, seed=args.seed,
+                             checkpoint_every=args.checkpoint_every,
                              coalesce_window_s=args.coalesce_window,
                              coalesce_max_requests=args.coalesce_max,
                              sampler_backend=args.sampler_backend,
@@ -280,6 +296,7 @@ def main() -> None:
                   else args.motif.split(","))
         deltas = [int(d) for d in str(args.delta).split(",")]
         cfg = EstimateConfig(chunk=args.chunk, seed=args.seed,
+                             checkpoint_every=args.checkpoint_every,
                              sampler_backend=args.sampler_backend,
                              depsum_backend=args.depsum_backend)
         with StreamingSession(config=cfg, horizon=args.horizon,
@@ -312,6 +329,7 @@ def main() -> None:
 
         from ..api import EstimateConfig, Session, serve_loop
         cfg = EstimateConfig(chunk=args.chunk, seed=args.seed,
+                             checkpoint_every=args.checkpoint_every,
                              coalesce_window_s=args.coalesce_window,
                              coalesce_max_requests=args.coalesce_max,
                              sampler_backend=args.sampler_backend,
@@ -343,6 +361,7 @@ def main() -> None:
         jobs = [(m, d, args.k) for m in motifs for d in deltas]
         exact_cache: dict = {}
         for res in estimate_many(g, jobs, seed=args.seed, chunk=args.chunk,
+                                 checkpoint_every=args.checkpoint_every,
                                  sampler_backend=args.sampler_backend,
                                  backend=args.depsum_backend, mesh=mesh):
             print(f"delta={res.delta}  fused={res.fused_jobs}  "
@@ -361,6 +380,7 @@ def main() -> None:
     motif = get_motif(motifs[0])
     res = estimate(g, motif, deltas[0], args.k, seed=args.seed,
                    chunk=args.chunk, checkpoint_path=args.checkpoint,
+                   checkpoint_every=args.checkpoint_every,
                    sampler_backend=args.sampler_backend,
                    depsum_backend=args.depsum_backend, mesh=mesh)
     print(res.summary())

@@ -3,14 +3,16 @@
 Three kinds, chosen for what the caller should *do* next:
 
 * ``retryable`` — transient device/host conditions (device OOM /
-  RESOURCE_EXHAUSTED, connection resets, timeouts): retry with backoff,
-  then degrade down the ladder (engine: pallas -> xla -> smaller
-  dispatch windows).
+  RESOURCE_EXHAUSTED while a program runs, connection resets,
+  timeouts): retry with backoff, then degrade down the ladder (engine:
+  pallas -> xla -> smaller dispatch windows).
 * ``bad_request`` — the input is wrong (unknown motif, malformed
   fields): retrying is useless, but the server stays up and answers
   ``ok: false``.
-* ``fatal`` — everything else (logic errors, assertion failures):
-  never retried; surfaces to the caller.
+* ``fatal`` — everything else (logic errors, assertion failures, and a
+  compiler's refusal of a program — the same program is refused again
+  on every attempt, whatever status code the refusal carries): never
+  retried, never laddered to another backend; surfaces to the caller.
 * ``overloaded`` — admission control shed the request before executing
   it (a bounded per-tenant quota was full — the gateway's backpressure
   seam).  The client backs off and resubmits; the server never retries
@@ -68,6 +70,18 @@ _TRANSIENT_STATUS = ("RESOURCE_EXHAUSTED", "UNAVAILABLE",
                      "DEADLINE_EXCEEDED", "ABORTED", "CANCELLED",
                      "OUT OF MEMORY", "OOM")
 
+# markers of a compiler's refusal, checked before the transient ones.  The
+# TPU compiler refuses a program over HBM with "RESOURCE_EXHAUSTED: XLA:TPU
+# compile permanent error. Ran out of memory in memory space hbm ...", a
+# kernel over VMEM with "RESOURCE_EXHAUSTED: Allocation (size=...) would
+# exceed memory (size=...)", and a kernel Mosaic cannot build with
+# "Mosaic failed to compile TPU kernel".  A refusal is deterministic:
+# retrying recompiles the same refusal, and a backend swap would hide
+# which program was refused (tests/test_tpu_compile.py feeds the real
+# compiler's refusals through classify)
+_COMPILE_REFUSAL = ("COMPILE PERMANENT ERROR", "WOULD EXCEED MEMORY",
+                    "FAILED TO COMPILE")
+
 
 def classify(exc: BaseException) -> str:
     """Map an exception to ``retryable`` / ``fatal`` / ``bad_request`` /
@@ -83,6 +97,8 @@ def classify(exc: BaseException) -> str:
     mro_names = {c.__name__ for c in type(exc).__mro__}
     if mro_names & set(_DEVICE_ERROR_NAMES):
         msg = str(exc).upper()
+        if any(marker in msg for marker in _COMPILE_REFUSAL):
+            return FATAL
         if any(status in msg for status in _TRANSIENT_STATUS):
             return RETRYABLE
         return FATAL

@@ -1,37 +1,7 @@
 """Small shared utilities."""
 from __future__ import annotations
 
-import functools
-import inspect
-
 import jax
-
-
-@functools.cache
-def get_shard_map():
-    """Version-tolerant ``shard_map``.
-
-    Newer jax exposes ``jax.shard_map`` (with a ``check_vma`` kwarg); the
-    pinned 0.4.x series only has ``jax.experimental.shard_map.shard_map``
-    (where the same knob is spelled ``check_rep``).  Returns a callable with
-    the modern signature that translates whichever spelling the underlying
-    implementation understands, so call sites can be written once against
-    the current API.
-    """
-    native = getattr(jax, "shard_map", None)
-    if native is None:
-        from jax.experimental.shard_map import shard_map as native
-    accepted = set(inspect.signature(native).parameters)
-
-    @functools.wraps(native)
-    def shard_map(f, *args, **kw):
-        if "check_vma" in kw and "check_vma" not in accepted:
-            kw["check_rep"] = kw.pop("check_vma")
-        if "check_rep" in kw and "check_rep" not in accepted:
-            kw["check_vma"] = kw.pop("check_rep")
-        return native(f, *args, **kw)
-
-    return shard_map
 
 
 def ensure_x64() -> None:

@@ -61,6 +61,30 @@ def test_preprocess_dedup(graph):
     assert planner.preprocess_hits == 0
 
 
+def test_concurrent_candidate_preprocess_matches_choose_tree(graph):
+    """``plan`` preprocesses its candidates in threads; the winner and
+    its arrays equal ``choose_tree``'s one-candidate-at-a-time ranking,
+    even with the interpreter switching threads as often as it can."""
+    import sys
+
+    from repro.core.estimator import choose_tree
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        planner = BatchPlanner(graph)
+        plans = {mn: planner.plan(get_motif(mn), DELTA)
+                 for mn in ("M5-3", "M4-2", "triangle")}
+    finally:
+        sys.setswitchinterval(was)
+    assert planner.preprocess_calls == len(planner._weights)
+    for mn, (tree, wts) in plans.items():
+        ref_tree, ref = choose_tree(graph, get_motif(mn), DELTA)
+        assert tree == ref_tree
+        for f in ("w_own", "ps_acc_own", "ps_pair_prev", "ps_win",
+                  "W_total"):
+            np.testing.assert_array_equal(getattr(wts, f), getattr(ref, f))
+
+
 def test_seed_override_and_job_spec(graph):
     job = as_job(("M4-2", DELTA, 512, 7))
     assert isinstance(job, Job) and job.seed == 7

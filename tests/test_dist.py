@@ -18,8 +18,7 @@ import sys
 sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.util import get_shard_map
-shard_map = get_shard_map()
+shard_map = jax.shard_map
 """
 
 

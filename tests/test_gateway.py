@@ -265,6 +265,16 @@ def test_health_and_stats_grow_per_tenant_blocks():
         assert s["epoch"] == 0 and s["subscriptions"] == 0
     assert stats["max_tenants"] == 4
     assert health["scheduler"]["quota"] == 16
+    # the device the process computes on, as jax reports it
+    import jax
+    assert health["device"] == dict(
+        platform=jax.devices()[0].platform,
+        kind=jax.devices()[0].device_kind, count=len(jax.devices()),
+        peak_bytes=health["device"]["peak_bytes"])
+    opened = [json.loads(ln) for ln in out.getvalue().splitlines()
+              if '"open_tenant"' in ln]
+    fin_open = next(r for r in opened if r["tenant"] == "fin")
+    assert fin_open["n"] > 0 and fin_open["m"] > 0
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,9 @@
 """Launch layer: cell construction + lower/compile on a small host mesh,
-and the dry-run record schema (subprocess: needs >1 device)."""
+the dry-run record schema (subprocess: needs >1 device), and where the
+launcher keeps its persistent compile cache."""
 from __future__ import annotations
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -64,3 +66,38 @@ def test_production_mesh_shapes():
         assert '("pod", "data", "model")' in src
         print("OK")
     """)
+
+
+def test_compile_cache_dir_fixed_unless_placed_from_outside(tmp_path):
+    """``use_compile_cache`` keeps a directory set from outside and
+    otherwise points at ``<checkout>/.jax_cache`` — one fixed path."""
+    import jax
+
+    from repro.launch.compile_cache import CHECKOUT, use_compile_cache
+    assert os.path.isdir(os.path.join(CHECKOUT, "src", "repro"))
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        jax.config.update("jax_compilation_cache_dir", None)
+        path = use_compile_cache()
+        assert path == os.path.join(CHECKOUT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_launcher_writes_cache_where_env_places_it(tmp_path):
+    """A launcher run with ``JAX_COMPILATION_CACHE_DIR`` set writes its
+    compiled programs there."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jc"),
+               PYTHONPATH=os.path.join(repo, "src"))
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.estimate", "--graph",
+         "powerlaw:n=150,m=2000", "--motif", "M5-3", "--delta", "3000",
+         "--k", "512", "--chunk", "256"],
+        capture_output=True, text=True, cwd=repo, env=env, timeout=300)
+    assert r.returncode == 0, r.stderr
+    assert any(f.endswith("-cache") for f in os.listdir(tmp_path / "jc"))

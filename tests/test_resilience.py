@@ -180,6 +180,36 @@ def test_pallas_oom_ladders_to_xla_bit_identical(graph):
     assert RSTATS.ladder_steps == steps0 + 1
 
 
+def test_compile_refusal_is_fatal_not_laddered(graph):
+    """A compiler's refusal is deterministic: it surfaces as ``fatal``
+    with no retry and no pallas -> xla swap, even though its status is
+    RESOURCE_EXHAUSTED.  A device OOM while a program runs stays
+    retryable.  (The messages are the TPU compiler's and runtime's own
+    texts; tests/test_tpu_compile.py classifies real refusals.)"""
+    class JaxRuntimeError(RuntimeError):
+        pass
+
+    hbm = ("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out "
+           "of memory in memory space hbm. Used 20.00G of 15.75G hbm.")
+    vmem = ("RESOURCE_EXHAUSTED: Allocation (size=268435456) would exceed "
+            "memory (size=134217728) :: #allocation2 [space=vmem]")
+    mosaic = "INTERNAL: Mosaic failed to compile TPU kernel: bad layout"
+    for msg in (hbm, vmem, mosaic):
+        assert classify(JaxRuntimeError(msg)) == "fatal"
+    assert classify(JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to "
+        "allocate 8.00G. That was not possible. There are 6.23G free."
+    )) == "retryable"
+
+    r0, steps0 = RSTATS.retries, RSTATS.ladder_steps
+    with FaultInjector([FaultSpec("engine.dispatch", tag="pallas", hits=None,
+                                  exc=JaxRuntimeError, message=hbm)]) as inj:
+        with pytest.raises(JaxRuntimeError, match="compile permanent error"):
+            _est(graph, sampler_backend="pallas")
+    assert RSTATS.retries == r0 and RSTATS.ladder_steps == steps0
+    assert sum(1 for (_, _, _, fired) in inj.log if fired) == 1
+
+
 def test_ladder_isolates_fused_siblings(graph):
     """Only the failing cohort degrades: a second request in the SAME
     submit window but a different plan group keeps its pallas backend

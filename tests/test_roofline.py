@@ -104,7 +104,6 @@ def test_collectives_counted_inside_loops(tmp_path):
         import sys
         sys.path.insert(0, "src")
         from repro.roofline.hlo_cost import hlo_cost
-        from repro.util import get_shard_map
         mesh = jax.make_mesh((4,), ("data",))
 
         def f(x):
@@ -113,8 +112,8 @@ def test_collectives_counted_inside_loops(tmp_path):
             c, _ = jax.lax.scan(body, x, None, length=6)
             return c
 
-        fn = get_shard_map()(f, mesh=mesh, in_specs=P(None, "data"),
-                             out_specs=P(None, "data"), check_vma=False)
+        fn = jax.shard_map(f, mesh=mesh, in_specs=P(None, "data"),
+                           out_specs=P(None, "data"), check_vma=False)
         comp = jax.jit(fn).lower(
             jax.ShapeDtypeStruct((64, 64), jnp.float32)).compile()
         c = hlo_cost(comp.as_text())
