@@ -1,0 +1,23 @@
+"""sampler_ns_per_sample -- layer: sampler and validator window program
+(core/sampler.py); source: device_trace; moves: samples_per_s.
+
+Device time of the innermost ops under the ``sample`` named scope in the
+captured ``*window*`` executions (``bench/xplane.py``), in ns, over the
+samples on the captured ``engine.dispatch`` annotations.  None where the
+capture has no such scope or annotation."""
+import os
+
+from bench import xplane
+
+PROFILE = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "out", "profile")
+
+
+def read(ctx):
+    planes = xplane.capture(ctx, PROFILE)
+    if planes is None:
+        return None
+    ns, samples = xplane.phase_ns(planes), xplane.dispatched_samples(planes)
+    if not ns or not samples or not ns["sample"]:
+        return None
+    return ns["sample"] / samples

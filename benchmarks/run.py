@@ -1316,131 +1316,11 @@ def gateway_bench(fast: bool):
     print(f"# wrote {path}", flush=True)
 
 
-def obs_bench(fast: bool):
-    """Cost of the telemetry layer (repro.obs) at each ``REPRO_OBS``
-    level.  Writes BENCH_obs.json.
-
-    * seam microcosts — ``obs.span`` enter/exit ns/call at off/metrics/
-      trace (off must be near-free: the span still times, but records
-      nothing and touches no thread-local stack), plus registry counter
-      inc and histogram observe ns/call;
-    * end-to-end overhead — warm ``estimate()`` reps with the process
-      obs level forced to off / metrics / trace; the acceptance bar is
-      ~zero overhead at ``off`` and < 2% at ``metrics``, with
-      bit-identical estimates at every level (obs never touches keys or
-      traced code).
-    """
-    import json
-    import os
-
-    from repro import obs
-    from repro.core.estimator import estimate
-    from repro.core.motif import get_motif
-    from repro.graphs import powerlaw_temporal_graph
-
-    # -- seam microcosts -------------------------------------------------
-    n = 100_000
-    span_ns = {}
-    for lvl in ("off", "metrics", "trace"):
-        obs.set_level(lvl)
-        t0 = time.perf_counter()
-        for _ in range(n):
-            with obs.span("bench.site", stage="drain"):
-                pass
-        span_ns[lvl] = 1e9 * (time.perf_counter() - t0) / n
-        emit("obs", "span", f"{lvl}_ns_per_call", f"{span_ns[lvl]:.0f}")
-    obs.RECORDER.clear()                   # drop the microbench spans
-
-    obs.set_level("metrics")
-    scratch = obs.Registry()               # keep the scrape surface clean
-    ctr = scratch.counter("bench_scratch_total", "obs bench scratch")
-    hist = scratch.histogram("bench_scratch_seconds", "obs bench scratch")
-    t0 = time.perf_counter()
-    for _ in range(n):
-        ctr.inc()
-    counter_ns = 1e9 * (time.perf_counter() - t0) / n
-    t0 = time.perf_counter()
-    for _ in range(n):
-        hist.observe(1e-4)
-    observe_ns = 1e9 * (time.perf_counter() - t0) / n
-    emit("obs", "registry", "counter_inc_ns", f"{counter_ns:.0f}")
-    emit("obs", "registry", "histogram_observe_ns", f"{observe_ns:.0f}")
-
-    # -- end-to-end: warm estimate() at each level -----------------------
-    g = powerlaw_temporal_graph(n=300, m=4_000, time_span=60_000, seed=7)
-    m = get_motif("M5-3")
-    k = 1 << (12 if fast else 14)
-    chunk, ck = 1 << 10, 2
-    reps = 3 if fast else 8
-
-    def leg():
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            r = estimate(g, m, 3_000, k, seed=0, chunk=chunk,
-                         checkpoint_every=ck)
-        return (time.perf_counter() - t0) / reps, r
-
-    try:
-        obs.set_level("off")
-        leg()                              # warm every cache, untimed
-        times, results = {}, {}
-        for lvl in ("off", "metrics", "trace"):
-            obs.set_level(lvl)
-            times[lvl], results[lvl] = leg()
-        spans_at_trace = len(obs.RECORDER)
-    finally:
-        obs.set_level(None)                # back to the REPRO_OBS knob
-        obs.RECORDER.clear()
-    assert (results["off"].estimate == results["metrics"].estimate
-            == results["trace"].estimate)  # obs never moves bits
-    overhead = {lvl: 100.0 * (times[lvl] - times["off"])
-                / max(times["off"], 1e-9) for lvl in ("metrics", "trace")}
-    emit("obs", "estimate", "warm_off_s", f"{times['off']:.4f}")
-    emit("obs", "estimate", "warm_metrics_s", f"{times['metrics']:.4f}")
-    emit("obs", "estimate", "warm_trace_s", f"{times['trace']:.4f}")
-    emit("obs", "estimate", "metrics_overhead_pct",
-         f"{overhead['metrics']:.2f}")
-    emit("obs", "estimate", "trace_overhead_pct", f"{overhead['trace']:.2f}")
-    emit("obs", "estimate", "identical_results", True)
-
-    record = dict(
-        span_ns_per_call={lvl: round(v, 1) for lvl, v in span_ns.items()},
-        counter_inc_ns=round(counter_ns, 1),
-        histogram_observe_ns=round(observe_ns, 1),
-        estimate=dict(k=k, chunk=chunk, checkpoint_every=ck, reps=reps,
-                      warm_off_s=round(times["off"], 4),
-                      warm_metrics_s=round(times["metrics"], 4),
-                      warm_trace_s=round(times["trace"], 4),
-                      metrics_overhead_pct=round(overhead["metrics"], 2),
-                      trace_overhead_pct=round(overhead["trace"], 2),
-                      spans_recorded_at_trace=spans_at_trace,
-                      identical_results=True),
-        methodology=("seam: tight-loop ns/call of obs.span at each "
-                     "forced level (off = timing only, no recording; "
-                     "metrics adds one stage-histogram observe; trace "
-                     "adds stack bookkeeping + a ring append), and of "
-                     "Counter.inc / Histogram.observe on a scratch "
-                     "registry.  end-to-end: warm estimate() reps with "
-                     "obs.set_level forced per leg, same seed — "
-                     "estimates asserted bit-identical across levels.  "
-                     "The estimate-level deltas are noise-dominated at "
-                     "these runtimes (the per-window span count is tiny "
-                     "next to the device work) — the acceptance bar is "
-                     "|overhead| small at off/metrics, not its sign."),
-    )
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "BENCH_obs.json")
-    with open(path, "w") as f:
-        json.dump(record, f, indent=2)
-    print(f"# wrote {path}", flush=True)
-
-
 BENCHES = dict(t3=t3_speed, t4=t4_accuracy, t5=t5_small, t6=t6_ablation,
                t7=t7_trees, f6=f6_sweep, perf=perf_micro, batch=batch_bench,
                sampler=sampler_bench, engine=engine_bench, serve=serve_bench,
                stream=stream_bench, multimotif=multimotif_bench,
-               resilience=resilience_bench, gateway=gateway_bench,
-               obs=obs_bench)
+               resilience=resilience_bench, gateway=gateway_bench)
 
 
 def main() -> None:

@@ -395,6 +395,4 @@ if [[ "${CI_BENCH:-0}" == "1" ]]; then
     python -m benchmarks.run --suite resilience --fast
   PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
     python -m benchmarks.run --suite gateway --fast
-  PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python -m benchmarks.run --suite obs --fast
 fi

@@ -30,6 +30,7 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Iterable, Sequence
 
+from .. import obs
 from .estimator import EstimateResult
 from .graph import TemporalGraph
 from .motif import TemporalMotif, get_motif
@@ -122,7 +123,8 @@ class BatchPlanner:
         side by side; the device still runs the DPs one at a time, so
         peak device memory is that of sequential DPs.  The ranking below
         reads the candidates in order, so the choice is the sequential
-        one."""
+        one.  The workers run under the caller's stage and trace
+        (``obs.bind``), so their compiles count as ``compile.preprocess``."""
         pkey = (motif, int(delta))
         if pkey in self._plans:
             return self._plans[pkey]
@@ -133,9 +135,9 @@ class BatchPlanner:
             key = self._key(tree, delta)
             if key not in self._weights:
                 todo.setdefault(key, tree)
+        run = obs.bind(lambda tree: self._preprocess(tree, delta))
         with ThreadPoolExecutor(max(1, len(todo))) as pool:
-            done = dict(zip(todo, pool.map(
-                lambda tree: self._preprocess(tree, delta), todo.values())))
+            done = dict(zip(todo, pool.map(run, todo.values())))
         self._weights.update(done)
         self.preprocess_calls += len(done)
         self.preprocess_hits += len(cands) - len(done)

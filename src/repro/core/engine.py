@@ -147,7 +147,8 @@ def make_engine_window_fn(trees, chunk: int, Lmax: int = 16,
     M = len(lanes)
 
     def chunk_sums(dev, wts, base_keys, j):
-        keys = jax.vmap(lambda bk: jax.random.fold_in(bk, j))(base_keys)
+        with jax.named_scope("sample"):
+            keys = jax.vmap(lambda bk: jax.random.fold_in(bk, j))(base_keys)
         return cc_fn(dev, wts, bs_fn(dev, wts, keys))
 
     if mesh is not None and (not data_axes(mesh)
@@ -163,7 +164,8 @@ def make_engine_window_fn(trees, chunk: int, Lmax: int = 16,
         def window(dev, wts, base_keys, j0, n):
             def step(acc, j):
                 out = chunk_sums(dev, wts, base_keys, j)
-                return {k: acc[k] + out[k] for k in _ACC_KEYS}, None
+                with jax.named_scope("score"):
+                    return {k: acc[k] + out[k] for k in _ACC_KEYS}, None
 
             acc0 = {k: jnp.zeros((base_keys.shape[0], M), jnp.int64)
                     for k in _ACC_KEYS}
@@ -184,8 +186,10 @@ def make_engine_window_fn(trees, chunk: int, Lmax: int = 16,
             def step(acc, i):
                 off = d + i * D
                 out = chunk_sums(dev, wts, base_keys, j0 + off)
-                live = (off < n).astype(jnp.int64)
-                return {k: acc[k] + out[k] * live for k in _ACC_KEYS}, None
+                with jax.named_scope("score"):
+                    live = (off < n).astype(jnp.int64)
+                    return {k: acc[k] + out[k] * live
+                            for k in _ACC_KEYS}, None
 
             acc0 = {k: jnp.zeros((base_keys.shape[0], M), jnp.int64)
                     for k in _ACC_KEYS}
@@ -270,11 +274,6 @@ _LRU_WINDOW_HIT = _LRU_EVENTS.labels(cache="window", event="hit")
 _LRU_WINDOW_MISS = _LRU_EVENTS.labels(cache="window", event="miss")
 _LRU_WITNESS_HIT = _LRU_EVENTS.labels(cache="witness", event="hit")
 _LRU_WITNESS_MISS = _LRU_EVENTS.labels(cache="witness", event="miss")
-
-_SAMPLES_PER_S = obs.REGISTRY.gauge(
-    "repro_sampler_samples_per_s",
-    "sampler throughput over the most recent cohort window dispatch")
-
 
 def _cache_capacity() -> int:
     return max(1, get_knob("REPRO_ENGINE_CACHE"))
@@ -456,12 +455,14 @@ class EngineStats(obs.CounterBlock):
     ``cohort_motif_lanes``  distinct motif lanes over those windows
     ``samples_shared``      samples consumed without being redrawn
     ``witness_dispatches``  witness reservoir windows dispatched
+    ``samples_drawn``       samples drawn (chunk x chunks x distinct
+                            streams per cohort window; take its rate)
     """
 
     _PREFIX = "repro_engine"
     _FIELDS = ("dispatches", "fused_dispatches", "job_windows",
                "tree_cohorts", "cohort_motif_lanes", "samples_shared",
-               "witness_dispatches")
+               "witness_dispatches", "samples_drawn")
     _DOCS = {
         "dispatches": "compiled window programs launched",
         "fused_dispatches": "dispatches carrying more than one job",
@@ -470,6 +471,7 @@ class EngineStats(obs.CounterBlock):
         "cohort_motif_lanes": "distinct motif lanes over cohort windows",
         "samples_shared": "samples consumed without being redrawn",
         "witness_dispatches": "witness reservoir windows dispatched",
+        "samples_drawn": "samples drawn by cohort window dispatches",
     }
 
     @property
@@ -833,13 +835,14 @@ def run_plan(plan: ExecutionPlan, on_window=None) -> list[EstimateResult]:
                         keys.append(job.base_key)
                 pad = group.n_streams - len(keys)
                 base_keys = jnp.stack(keys + [keys[0]] * pad)
+                drawn = plan.chunk * n * len(keys)
                 profiling = obs.profile_armed()
                 if profiling:
                     obs.profile_window_start()
                 with obs.span("engine.dispatch", stage="dispatch",
                               trace=cjobs[0].trace,
                               backend=cjobs[0].backend, j0=int(j0),
-                              n=int(n), jobs=len(cjobs),
+                              n=int(n), samples=drawn, jobs=len(cjobs),
                               streams=len(keys), rung=cjobs[0].max_window,
                               plan_key=str(group.key.signature)) as sp:
                     sums, n_disp = _run_cohort_window(plan, group, get_fn,
@@ -849,8 +852,6 @@ def run_plan(plan: ExecutionPlan, on_window=None) -> list[EstimateResult]:
                 if profiling:
                     obs.profile_window_end()
                 dt = sp.elapsed_s
-                if obs.enabled() and dt > 0:
-                    _SAMPLES_PER_S.set(plan.chunk * n * len(keys) / dt)
                 plan.dispatches += n_disp
                 STATS.dispatches += n_disp
                 STATS.job_windows += len(cjobs)
@@ -860,6 +861,7 @@ def run_plan(plan: ExecutionPlan, on_window=None) -> list[EstimateResult]:
                 STATS.cohort_motif_lanes += len({j.lane for j in cjobs})
                 STATS.samples_shared += (plan.chunk * n
                                          * (len(cjobs) - len(keys)))
+                STATS.samples_drawn += drawn
                 for job in cjobs:
                     wsums = {kk: int(sums[kk][row_of[job.seed], job.lane])
                              for kk in _ACC_KEYS}

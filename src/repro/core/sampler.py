@@ -17,6 +17,13 @@ Pipeline per sample (all steps data-parallel over K):
    minus the parallel-edge pair list (Claim 4.8) — sampled by bisecting
    ``g(p) = Lambda_prefix(p) - El_prefix(cross(p))`` where ``cross`` is a
    nested bisection into the pair position sub-sequence.
+
+The phases carry ``jax.named_scope`` names — ``sample/window``,
+``sample/center``, ``sample/child`` (every bound, pair and inverse-CDF
+bisection of the children) and ``sample/vertex_map`` — and the cohort
+reduction ``score``, so a profiler trace of the window program names
+its ops by phase.  Scopes are HLO metadata only: the samples are
+unchanged.
 """
 from __future__ import annotations
 
@@ -148,9 +155,10 @@ def make_cohort_count_fn(lane_trees, K: int, Lmax: int = 16,
 
     def fn(dev, wts, samples):
         outs = [cf(dev, wts, samples) for cf in count_fns]
-        return {k: jnp.stack([o[k].sum(axis=1).astype(jnp.int64)
-                              for o in outs], axis=1)
-                for k in keys}
+        with jax.named_scope("score"):
+            return {k: jnp.stack([o[k].sum(axis=1).astype(jnp.int64)
+                                  for o in outs], axis=1)
+                    for k in keys}
 
     return fn
 
@@ -234,6 +242,10 @@ def _make_sample_fn_xla(tree: SpanningTree, K: int):
     nv = tree.motif.num_vertices
 
     def fn(dev, wts, key):
+        with jax.named_scope("sample"):
+            return draw(dev, wts, key)
+
+    def draw(dev, wts, key):
         t = dev["t"]
         it = bisect_iters(t.shape[0])
         delta = jnp.asarray(wts.delta, jnp.int64)
@@ -242,102 +254,108 @@ def _make_sample_fn_xla(tree: SpanningTree, K: int):
         keys = jax.random.split(key, S + 2)
 
         # -- 1. window ---------------------------------------------------
-        W = jnp.maximum(wts.W_total, 1)
-        x = jax.random.randint(keys[0], (K,), 0, W, dtype=jnp.int64)
-        # trip count from the STATIC window-array length (>= the traced
-        # real q; extra iterations are converged no-ops) — wts.q itself
-        # is traced so epoch snapshots never retrace on window count
-        itq = converge_iters(wts.q_pad)
-        win = seg_upper_bound(wts.ps_win, jnp.zeros((K,), jnp.int64),
-                              jnp.full((K,), wts.q, jnp.int64), x,
-                              iters=itq) - 1
-        win = jnp.clip(win, 0, wts.q - 1)
-        resid = x - wts.ps_win[win]
+        with jax.named_scope("window"):
+            W = jnp.maximum(wts.W_total, 1)
+            x = jax.random.randint(keys[0], (K,), 0, W, dtype=jnp.int64)
+            # trip count from the STATIC window-array length (>= the traced
+            # real q; extra iterations are converged no-ops) — wts.q itself
+            # is traced so epoch snapshots never retrace on window count
+            itq = converge_iters(wts.q_pad)
+            win = seg_upper_bound(wts.ps_win, jnp.zeros((K,), jnp.int64),
+                                  jnp.full((K,), wts.q, jnp.int64), x,
+                                  iters=itq) - 1
+            win = jnp.clip(win, 0, wts.q - 1)
+            resid = x - wts.ps_win[win]
 
         # -- 2. center edge ----------------------------------------------
-        lo = wts.win_lo[win]
-        mid = wts.win_mid[win]
-        hi = wts.win_hi[win]
-        Cc = _two_piece(wts.ps_acc_own[r], wts.ps_acc_prev[r], lo, mid)
-        e0 = monotone_find(lambda p: Cc(p), lo, hi, resid, iters=it)
+        with jax.named_scope("center"):
+            lo = wts.win_lo[win]
+            mid = wts.win_mid[win]
+            hi = wts.win_hi[win]
+            Cc = _two_piece(wts.ps_acc_own[r], wts.ps_acc_prev[r], lo, mid)
+            e0 = monotone_find(lambda p: Cc(p), lo, hi, resid, iters=it)
 
         edges = [None] * S
         edges[r] = e0
 
         # -- 3. children, top-down (static schedule) ----------------------
-        for s in tree.topo_down:
-            e = edges[s]
-            u = dev["src"][e].astype(jnp.int64)
-            v = dev["dst"][e].astype(jnp.int64)
-            te = t[e]
-            for d in tree.deps[s]:
-                c = d.child
-                meet = u if d.meet_end == 0 else v
-                if d.alpha == OUT:
-                    ptr, csr_t = dev["out_ptr"], dev["out_t"]
-                    csr_edge, pair_pos = dev["out_edge"], dev["pair_pos_out"]
-                else:
-                    ptr, csr_t = dev["in_ptr"], dev["in_t"]
-                    csr_edge, pair_pos = dev["in_edge"], dev["pair_pos_in"]
-                p0 = ptr[meet]
-                p1 = ptr[meet + 1]
-                if d.beta == BEFORE:
-                    tlo = jnp.maximum(te - delta, win * wd)
-                    thi = te
-                else:
-                    tlo = te
-                    thi = jnp.minimum(te + delta, (win + 2) * wd - 1)
-                brk = (win + 1) * wd
-                plo = seg_lower_bound(csr_t, p0, p1, tlo, iters=it)
-                phi = seg_upper_bound(csr_t, p0, p1, thi, iters=it)
-                pmid = jnp.clip(seg_lower_bound(csr_t, p0, p1, brk,
-                                                iters=it), plo, phi)
-                CL = _two_piece(wts.ps_acc_own[c], wts.ps_acc_prev[c],
-                                plo, pmid)
-
-                if wts.use_c2:
+        with jax.named_scope("child"):
+            for s in tree.topo_down:
+                e = edges[s]
+                u = dev["src"][e].astype(jnp.int64)
+                v = dev["dst"][e].astype(jnp.int64)
+                te = t[e]
+                for d in tree.deps[s]:
+                    c = d.child
+                    meet = u if d.meet_end == 0 else v
                     if d.alpha == OUT:
-                        pid = (dev["pair_id"] if d.meet_end == 0
-                               else dev["rev_pair_id"])[e]
+                        ptr, csr_t = dev["out_ptr"], dev["out_t"]
+                        csr_edge = dev["out_edge"]
+                        pair_pos = dev["pair_pos_out"]
                     else:
-                        pid = (dev["rev_pair_id"] if d.meet_end == 0
-                               else dev["pair_id"])[e]
-                    pid = pid.astype(jnp.int64)
-                    has = pid >= 0
-                    pid0 = jnp.maximum(pid, 0)
-                    q0 = dev["pair_ptr"][pid0]
-                    q1 = jnp.where(has, dev["pair_ptr"][pid0 + 1], q0)
-                    pt = dev["pair_t"]
-                    qlo = seg_lower_bound(pt, q0, q1, tlo, iters=it)
-                    qhi = seg_upper_bound(pt, q0, q1, thi, iters=it)
-                    qmid = jnp.clip(seg_lower_bound(pt, q0, q1, brk,
-                                                    iters=it), qlo, qhi)
-                    CE = _two_piece(wts.ps_pair_own[c], wts.ps_pair_prev[c],
-                                    qlo, qmid)
+                        ptr, csr_t = dev["in_ptr"], dev["in_t"]
+                        csr_edge, pair_pos = dev["in_edge"], dev["pair_pos_in"]
+                    p0 = ptr[meet]
+                    p1 = ptr[meet + 1]
+                    if d.beta == BEFORE:
+                        tlo = jnp.maximum(te - delta, win * wd)
+                        thi = te
+                    else:
+                        tlo = te
+                        thi = jnp.minimum(te + delta, (win + 2) * wd - 1)
+                    brk = (win + 1) * wd
+                    plo = seg_lower_bound(csr_t, p0, p1, tlo, iters=it)
+                    phi = seg_upper_bound(csr_t, p0, p1, thi, iters=it)
+                    pmid = jnp.clip(seg_lower_bound(csr_t, p0, p1, brk,
+                                                    iters=it), plo, phi)
+                    CL = _two_piece(wts.ps_acc_own[c], wts.ps_acc_prev[c],
+                                    plo, pmid)
 
-                    def g(p, CL=CL, CE=CE, pair_pos=pair_pos, qlo=qlo,
-                          qhi=qhi, it=it):
-                        cross = seg_lower_bound(pair_pos, qlo, qhi, p,
-                                                iters=it)
-                        return CL(p) - CE(cross)
-                else:
-                    def g(p, CL=CL):
-                        return CL(p)
+                    if wts.use_c2:
+                        if d.alpha == OUT:
+                            pid = (dev["pair_id"] if d.meet_end == 0
+                                   else dev["rev_pair_id"])[e]
+                        else:
+                            pid = (dev["rev_pair_id"] if d.meet_end == 0
+                                   else dev["pair_id"])[e]
+                        pid = pid.astype(jnp.int64)
+                        has = pid >= 0
+                        pid0 = jnp.maximum(pid, 0)
+                        q0 = dev["pair_ptr"][pid0]
+                        q1 = jnp.where(has, dev["pair_ptr"][pid0 + 1], q0)
+                        pt = dev["pair_t"]
+                        qlo = seg_lower_bound(pt, q0, q1, tlo, iters=it)
+                        qhi = seg_upper_bound(pt, q0, q1, thi, iters=it)
+                        qmid = jnp.clip(seg_lower_bound(pt, q0, q1, brk,
+                                                        iters=it), qlo, qhi)
+                        CE = _two_piece(wts.ps_pair_own[c],
+                                        wts.ps_pair_prev[c], qlo, qmid)
 
-                Wx = g(phi)
-                rx = jax.random.randint(keys[2 + c], (K,), 0,
-                                        jnp.maximum(Wx, 1), dtype=jnp.int64)
-                pstar = monotone_find(g, plo, phi, rx, iters=it)
-                edges[c] = csr_edge[pstar].astype(jnp.int64)
+                        def g(p, CL=CL, CE=CE, pair_pos=pair_pos, qlo=qlo,
+                              qhi=qhi, it=it):
+                            cross = seg_lower_bound(pair_pos, qlo, qhi, p,
+                                                    iters=it)
+                            return CL(p) - CE(cross)
+                    else:
+                        def g(p, CL=CL):
+                            return CL(p)
+
+                    Wx = g(phi)
+                    rx = jax.random.randint(keys[2 + c], (K,), 0,
+                                            jnp.maximum(Wx, 1),
+                                            dtype=jnp.int64)
+                    pstar = monotone_find(g, plo, phi, rx, iters=it)
+                    edges[c] = csr_edge[pstar].astype(jnp.int64)
 
         E = jnp.stack(edges, axis=1)  # [K, S]
         # vertex map from the static vertex_source table
-        cols = []
-        for vtx in range(nv):
-            s_loc, end = tree.vertex_source[vtx]
-            arr = dev["src"] if end == 0 else dev["dst"]
-            cols.append(arr[E[:, s_loc]].astype(jnp.int64))
-        phi_v = jnp.stack(cols, axis=1)  # [K, nv]
+        with jax.named_scope("vertex_map"):
+            cols = []
+            for vtx in range(nv):
+                s_loc, end = tree.vertex_source[vtx]
+                arr = dev["src"] if end == 0 else dev["dst"]
+                cols.append(arr[E[:, s_loc]].astype(jnp.int64))
+            phi_v = jnp.stack(cols, axis=1)  # [K, nv]
         return dict(edges=E, window=win, phi_v=phi_v)
 
     return jax.jit(fn)

@@ -44,7 +44,8 @@ INF = jnp.iinfo(jnp.int64).max // 4
 
 
 def make_count_fn(tree: SpanningTree, K: int, Lmax: int = 16):
-    """Jitted ``fn(dev, wts, samples) -> dict`` of per-sample counts/flags."""
+    """Jitted ``fn(dev, wts, samples) -> dict`` of per-sample counts/flags
+    (its ops carry the ``validate`` named scope)."""
     motif = tree.motif
     S = tree.num_edges
     nv = motif.num_vertices
@@ -69,6 +70,10 @@ def make_count_fn(tree: SpanningTree, K: int, Lmax: int = 16):
         return local_of_rank[c[0]] if c else None
 
     def fn(dev, wts, samples):
+        with jax.named_scope("validate"):
+            return count(dev, wts, samples)
+
+    def count(dev, wts, samples):
         it = converge_iters(dev["t"].shape[0])
         E = samples["edges"]          # [K, S]
         phi_v = samples["phi_v"]      # [K, nv]

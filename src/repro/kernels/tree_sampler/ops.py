@@ -166,6 +166,11 @@ def make_pallas_sample_fn(tree: SpanningTree, K: int, *, bk: int | None = None,
         interpret = jax.default_backend() != "tpu"
 
     def fn(dev, wts, key):
+        # the XLA sampler's top-level scope: a trace names both alike
+        with jax.named_scope("sample"):
+            return draw(dev, wts, key)
+
+    def draw(dev, wts, key):
         m = dev["t"].shape[0]
         it = bisect_iters(m)
         # static shape-derived trip count (wts.q is traced); == the old

@@ -209,6 +209,9 @@ def main() -> None:
                  "arms the profiler over the wire)")
     from .compile_cache import use_compile_cache
     use_compile_cache()
+    if args.serve:
+        from .. import obs
+        obs.install_compile_listener()      # compile time by stage
     if args.devices:
         from .mesh import force_host_device_count
         force_host_device_count(args.devices)

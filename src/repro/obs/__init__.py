@@ -27,9 +27,13 @@ sharing the request's trace id.
 
 **Metrics** (``metrics``) — a typed registry (:mod:`.registry`) of
 monotonic counters, gauges, and fixed log2-bucket latency histograms:
-per-stage latency (``repro_stage_seconds{stage=...}``), per-tenant
-request/advance histograms, sampler samples/s, window-program LRU
-hit/miss, WAL fsync latency.  ``engine.STATS`` and
+per-stage latency (``repro_stage_seconds{stage=...}``), compile time by
+stage (``repro_stage_seconds{stage="compile.<stage>"}``, from
+:mod:`.compiles`) and backend compiles by cache outcome
+(``repro_engine_compiles_total{stage,cache}``), per-tenant
+request/advance histograms, samples drawn
+(``repro_engine_samples_drawn_total``; take its rate), window-program
+LRU hit/miss, WAL fsync latency.  ``engine.STATS`` and
 ``resilience.STATS`` are :class:`~.registry.CounterBlock` facades over
 the same registry (their legacy attribute API still works), so every
 legacy counter is also a Prometheus series — scraped via the
@@ -37,7 +41,12 @@ legacy counter is also a Prometheus series — scraped via the
 
 **Profiling** — ``{"cmd": "profile", "windows": n}`` arms a one-shot
 ``jax.profiler`` capture around the next n engine window dispatches
-(server started with ``--profile-dir``).
+(server started with ``--profile-dir``).  During the capture every span
+is also a ``jax.profiler.TraceAnnotation`` (attrs as event stats, e.g.
+``engine.dispatch``'s ``samples``/``j0``/``n``), so host spans share the
+device trace's clock; the window program names its phases with
+``jax.named_scope`` (``sample/{window,center,child,vertex_map}``,
+``validate``, ``score``), which the trace's op metadata carries.
 
 Contracts
 ---------
@@ -46,9 +55,9 @@ Contracts
   counter (no entropy), and estimates are bit-identical at every
   ``REPRO_OBS`` level (pinned by goldens in ``tests/test_obs.py``).
 * **Structurally free when off.**  At ``off`` nothing is recorded —
-  no ring appends, no histogram updates, no span-stack bookkeeping
-  (``benchmarks/run.py --suite obs`` pins ~zero overhead at ``off``,
-  <2 % at ``metrics``).
+  no ring appends, no histogram updates, no span-stack or stage
+  bookkeeping, no profiler annotation (PERF.md gives the chip-measured
+  cost of tracing).
 * **Monotonic counters.**  Registry counters survive
   ``clear_window_cache()`` and session teardown; ``reset`` exists only
   as a test seam.
@@ -66,18 +75,20 @@ from .clock import monotonic, perf_counter
 from .registry import (BUCKET_BOUNDS, N_BUCKETS, REGISTRY, Counter,
                        CounterBlock, Family, Gauge, Histogram, Registry)
 from .trace import (METRICS, OFF, RECORDER, TRACE, FlightRecorder, Span,
-                    arm_profile, current_trace, enabled, event, level,
-                    level_name, new_trace, observe_stage, profile_armed,
-                    profile_status, profile_window_end,
+                    arm_profile, bind, current_stage, current_trace, enabled,
+                    event, level, level_name, new_trace, observe_stage,
+                    profile_armed, profile_status, profile_window_end,
                     profile_window_start, set_level, span, summary,
                     trace_context)
+from .compiles import install as install_compile_listener
 
 __all__ = [
     "monotonic", "perf_counter",
     "BUCKET_BOUNDS", "N_BUCKETS", "REGISTRY", "Counter", "CounterBlock",
     "Family", "Gauge", "Histogram", "Registry",
     "METRICS", "OFF", "RECORDER", "TRACE", "FlightRecorder", "Span",
-    "arm_profile", "current_trace", "enabled", "event", "level",
+    "arm_profile", "bind", "current_stage", "current_trace", "enabled",
+    "event", "install_compile_listener", "level",
     "level_name", "new_trace", "observe_stage", "profile_armed",
     "profile_status", "profile_window_end", "profile_window_start",
     "set_level", "span", "summary", "trace_context",

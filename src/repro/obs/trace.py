@@ -20,13 +20,25 @@ level); what varies with ``REPRO_OBS`` is recording:
   ring-buffer **flight recorder**, exportable as NDJSON via the
   ``{"cmd": "trace"}`` wire verb or ``--trace-out PATH``.
 
+From ``metrics`` up, a span that declares a ``stage=`` is also the
+thread's **current stage** until it closes (one thread-local set and
+restore; :func:`current_stage`) — what the compile listener
+(:mod:`.compiles`) charges a compile to — and :func:`bind` carries the
+caller's stage and trace into pool workers.
+
 Spans never enter traced code: ids derive from a process counter mixed
 through splitmix64 (no entropy, no wall-clock in keys), clock reads stay
 on the host, and estimates are bit-identical at every level.
 
 The :func:`profile` seam arms a one-shot ``jax.profiler`` capture around
 the next N engine window dispatches (wire verb ``{"cmd": "profile"}``).
-jax is imported lazily there — everything else in this module is stdlib.
+While a capture runs (and the level is ``metrics`` or above), every span
+also enters a ``jax.profiler.TraceAnnotation`` of its name, its scalar
+attrs as event stats, so host spans land on the device trace's clock;
+stage spans already open when the capture starts are annotated from
+that moment, and closed when it stops.  Outside a capture no annotation
+is built.  jax is imported lazily there — everything else in this
+module is stdlib.
 """
 from __future__ import annotations
 
@@ -117,6 +129,35 @@ class trace_context:
         return False
 
 
+def current_stage() -> str | None:
+    """The ``stage=`` of the innermost stage span open on this thread
+    (tracked from the ``metrics`` level up; None outside any)."""
+    sp = getattr(_CTX, "stage_span", None)
+    return None if sp is None else sp.stage
+
+
+def bind(fn):
+    """``fn`` wrapped to run under the calling thread's ambient trace and
+    innermost stage span — for work handed to a thread pool, whose
+    workers would otherwise charge their stage time (and compiles) to no
+    stage.  Returns ``fn`` itself below ``metrics``."""
+    if level() < METRICS:
+        return fn
+    outer = getattr(_CTX, "stage_span", None)
+    tid = outer.trace if outer is not None else current_trace()
+
+    def run(*args, **kwargs):
+        prev = (getattr(_CTX, "trace", None),
+                getattr(_CTX, "stage_span", None))
+        _CTX.trace, _CTX.stage_span = tid, outer
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _CTX.trace, _CTX.stage_span = prev
+
+    return run
+
+
 def _span_stack() -> list:
     stack = getattr(_CTX, "stack", None)
     if stack is None:
@@ -165,7 +206,8 @@ RECORDER = FlightRecorder(get_knob("REPRO_OBS_RING"))
 _STAGE_SECONDS = REGISTRY.histogram(
     "repro_stage_seconds",
     "per-stage serving latency (intake, queue_wait, preprocess, drain, "
-    "dispatch, device, emit, advance, wal_fsync)", labels=("stage",))
+    "dispatch, device, emit, advance, wal_fsync) and compile time by "
+    "stage (compile.<stage>)", labels=("stage",))
 _STAGE_CHILDREN: dict = {}          # stage -> Histogram child (hot-path cache)
 
 
@@ -180,7 +222,7 @@ class Span:
     """One timed host-side region (always times; records per level)."""
 
     __slots__ = ("name", "stage", "trace", "attrs", "span_id", "parent_id",
-                 "t0", "elapsed_s", "_recording")
+                 "t0", "elapsed_s", "_lvl", "_outer", "_ann")
 
     def __init__(self, name: str, stage: str | None, trace: str | None,
                  attrs: dict):
@@ -192,13 +234,15 @@ class Span:
         self.parent_id = 0
         self.t0 = 0.0
         self.elapsed_s = 0.0
-        self._recording = level() >= TRACE
+        self._lvl = level()
+        self._outer = None          # enclosing stage span (metrics and up)
+        self._ann = None            # open TraceAnnotation during a capture
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
 
     def __enter__(self) -> "Span":
-        if self._recording:
+        if self._lvl >= TRACE:
             stack = _span_stack()
             if self.trace is None:
                 self.trace = (stack[-1].trace if stack
@@ -208,15 +252,25 @@ class Span:
             stack.append(self)
         elif self.trace is None:
             self.trace = current_trace()
+        if self._lvl >= METRICS:
+            if self.stage is not None:
+                self._outer = getattr(_CTX, "stage_span", None)
+                _CTX.stage_span = self
+            if _PROFILE["active"]:
+                self._ann = _annotate(self)
         self.t0 = perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.elapsed_s = perf_counter() - self.t0
-        lvl = level()
-        if lvl >= METRICS and self.stage is not None:
-            _stage_hist(self.stage).observe(self.elapsed_s)
-        if self._recording:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self._lvl >= METRICS and self.stage is not None:
+            _CTX.stage_span = self._outer
+            if level() >= METRICS:
+                _stage_hist(self.stage).observe(self.elapsed_s)
+        if self._lvl >= TRACE:
             stack = _span_stack()
             if stack and stack[-1] is self:
                 stack.pop()
@@ -238,7 +292,8 @@ class Span:
 def span(name: str, *, stage: str | None = None, trace: str | None = None,
          **attrs) -> Span:
     """Open a span.  ``stage=`` feeds ``repro_stage_seconds`` at the
-    metrics level; other kwargs become recorder attrs at trace level."""
+    metrics level; other kwargs become recorder attrs at trace level,
+    and event stats of its annotation during a profiler capture."""
     return Span(name, stage, trace, attrs)
 
 
@@ -265,6 +320,9 @@ def observe_stage(stage: str, dt: float, *, trace: str | None = None,
     if lvl < METRICS:
         return
     _stage_hist(stage).observe(dt)
+    if _PROFILE["active"]:
+        # an instant on the profiler clock; the duration rides as a stat
+        _annotate_instant(f"stage.{stage}", dur_ns=int(dt * 1e9), **attrs)
     if lvl >= TRACE:
         if trace is None:
             trace = current_trace()
@@ -289,6 +347,40 @@ def summary() -> dict:
 _PROFILE = {"remaining": 0, "dir": None, "active": False, "error": None,
             "captured": 0}
 _PROFILE_LOCK = threading.Lock()
+
+
+def _stats(attrs: dict) -> dict:
+    """The attrs a TraceAnnotation can carry as event stats."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (int, float, str))}
+
+
+def _annotate(sp: Span):
+    """Open a ``TraceAnnotation`` for ``sp`` (only while a capture runs,
+    so jax is already imported)."""
+    from jax.profiler import TraceAnnotation
+    stats = _stats(sp.attrs)
+    if sp.trace is not None:
+        stats["trace"] = sp.trace
+    ann = TraceAnnotation(sp.name, **stats)
+    ann.__enter__()
+    return ann
+
+
+def _annotate_instant(name: str, **attrs) -> None:
+    from jax.profiler import TraceAnnotation
+    with TraceAnnotation(name, **_stats(attrs)):
+        pass
+
+
+def _open_stage_spans() -> list:
+    """This thread's open stage spans, outermost first."""
+    out = []
+    sp = getattr(_CTX, "stage_span", None)
+    while sp is not None:
+        out.append(sp)
+        sp = sp._outer
+    return out[::-1]
 
 
 def arm_profile(windows: int, logdir: str) -> dict:
@@ -321,6 +413,13 @@ def profile_window_start() -> None:
         except Exception as e:          # profiler failure must not kill serving
             _PROFILE["error"] = f"{type(e).__name__}: {e}"
             _PROFILE["remaining"] = 0
+            return
+        # the stage spans this window runs inside (a drain) opened before
+        # the capture: annotate them from here, so the trace sees them
+        if level() >= METRICS:
+            for sp in _open_stage_spans():
+                if sp._ann is None:
+                    sp._ann = _annotate(sp)
 
 
 def profile_window_end() -> None:
@@ -330,6 +429,12 @@ def profile_window_end() -> None:
         _PROFILE["remaining"] -= 1
         _PROFILE["captured"] += 1
         if _PROFILE["remaining"] <= 0:
+            # close the enclosing annotations while the capture still
+            # records them (an annotation open at the stop is dropped)
+            for sp in reversed(_open_stage_spans()):
+                if sp._ann is not None:
+                    sp._ann.__exit__(None, None, None)
+                    sp._ann = None
             try:
                 import jax
                 jax.profiler.stop_trace()

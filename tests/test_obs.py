@@ -352,3 +352,215 @@ def test_gateway_rse_trajectory_events():
     ks = [p["attrs"]["k_done"] for p in points]
     assert ks == sorted(ks) and ks[-1] == 4 * CHUNK
     assert all("rse" in p["attrs"] for p in points)
+
+
+# ---------------------------------------------------------------------------
+# profiler annotations, named scopes, compile time by stage
+# ---------------------------------------------------------------------------
+def _annotations(logdir) -> dict:
+    """``{name: [stats dict, ...]}`` of the host events in a capture."""
+    import glob
+
+    from jax.profiler import ProfileData
+    path = glob.glob(str(logdir / "**" / "*.xplane.pb"), recursive=True)[0]
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(dict(ev.stats))
+    return out
+
+
+def test_capture_carries_span_annotations(tmp_path):
+    """Spans open when the capture starts (the drain) and spans opened
+    during it (every dispatch) land in the trace with their attrs."""
+    obs.set_level("metrics")
+    with Session(_graph(), _cfg(checkpoint_every=2)) as s:
+        h = s.submit(Request(motif="M4-2", delta=DELTA, k=6 * CHUNK))
+        s.flush()
+        h.result()                          # compiled outside the capture
+        obs.arm_profile(5, str(tmp_path))
+        for seed in (1, 2):
+            h = s.submit(Request(motif="M4-2", delta=DELTA, k=6 * CHUNK,
+                                 seed=seed))
+            s.flush()
+            h.result()
+    assert obs.profile_status()["captured"] == 5
+    ann = _annotations(tmp_path)
+    disp = ann["engine.dispatch"]
+    assert len(disp) == 5
+    assert [d["j0"] for d in disp] == [0, 2, 4, 0, 2]
+    assert all(d["samples"] == 2 * CHUNK and d["n"] == 2 for d in disp)
+    # the first drain opened before the capture, the second ends after
+    # it: both are annotated over the part the capture saw
+    assert len(ann["session.drain"]) == 2
+    assert len(ann["engine.device"]) == 5
+
+
+def test_no_annotation_outside_a_capture(monkeypatch):
+    import jax.profiler
+
+    built = []
+
+    class Spy:
+        def __init__(self, name, **kw):
+            built.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+    obs.set_level("trace")
+    with obs.span("outer", stage="drain"):
+        with obs.span("inner", x=1):
+            obs.observe_stage("queue_wait", 0.001)
+    assert built == []
+    monkeypatch.setitem(obs.trace._PROFILE, "active", True)
+    with obs.span("outer", stage="drain"):
+        obs.observe_stage("queue_wait", 0.001)
+    monkeypatch.setitem(obs.trace._PROFILE, "active", False)
+    assert built == ["outer", "stage.queue_wait"]
+
+
+@pytest.mark.parametrize("lvl", ["off", "metrics"])
+def test_spans_import_no_jax(lvl):
+    import os
+    import subprocess
+    import sys
+    code = ("import sys\n"
+            "from repro import obs\n"
+            "with obs.span('a', stage='drain'):\n"
+            "    with obs.span('b', stage='dispatch') as sp:\n"
+            "        sp.set(n=1)\n"
+            "        obs.observe_stage('queue_wait', 0.5)\n"
+            "        assert obs.current_stage() == "
+            f"({'None' if lvl == 'off' else repr('dispatch')})\n"
+            "assert obs.current_stage() is None\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n")
+    env = dict(os.environ, REPRO_OBS=lvl,
+               PYTHONPATH=os.pathsep.join(p for p in sys.path if p))
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_window_program_names_its_phases(backend):
+    """The lowered window program's op metadata carries the scopes the
+    benchmark's trace readers attribute device time by."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.batch import BatchPlanner
+    g = _graph()
+    planner = BatchPlanner(g)
+    tree, wts = planner.plan(get_motif("M4-2"), DELTA)
+    fn = engine.make_engine_window_fn(tree, CHUNK, backend=backend)
+    keys = jnp.stack([jax.random.PRNGKey(0)])
+    hlo = fn.lower(planner.dev, wts, keys, 0, n=2).as_text(debug_info=True)
+    paths = set(re.findall(r'loc\("([^"]+)"', hlo))
+    want = ["sample", "validate", "score"]
+    if backend == "xla":
+        want = ["sample/window", "sample/center", "sample/child",
+                "sample/vertex_map", "validate", "score"]
+    for scope in want:
+        assert any(p == scope or p.startswith(scope + "/")
+                   or f"/{scope}/" in p for p in paths), scope
+
+
+@pytest.mark.parametrize("lvl", ["off", "metrics", "trace"])
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_goldens_at_every_level(lvl, backend):
+    """Solo and cohort-fused, the pinned golden estimates hold at every
+    telemetry level (scopes and annotations change no bit)."""
+    from pathlib import Path
+
+    from repro.graphs import powerlaw_temporal_graph
+    gold = json.loads((Path(__file__).parent
+                       / "golden_estimates.json").read_text())
+    g = powerlaw_temporal_graph(n=150, m=2_000, time_span=40_000, seed=11)
+    kw = dict(chunk=gold["chunk"], checkpoint_every=gold["checkpoint_every"],
+              sampler_backend=backend)
+    obs.set_level(lvl)
+    reqs = gold["requests"]
+    fused = estimate_many(
+        g, [(r["motif"], r["delta"], r["k"], r["seed"]) for r in reqs]
+        + [(reqs[1]["motif"], reqs[1]["delta"], reqs[1]["k"],
+            reqs[1]["seed"] + 1)], **kw)
+    for r, f in zip(reqs, fused):
+        solo = estimate(g, get_motif(r["motif"]), r["delta"], r["k"],
+                        seed=r["seed"], **kw)
+        for got in (solo, f):
+            assert (got.estimate, got.cnt2_sum, got.valid, got.W) == (
+                r["estimate"], r["cnt2"], r["valid"], r["W"])
+    assert fused[1].fused_jobs == 2         # two streams, one cohort
+
+
+def test_bind_carries_stage_and_trace_into_pool_workers():
+    from concurrent.futures import ThreadPoolExecutor
+    obs.set_level("metrics")
+    seen = []
+
+    def work():
+        seen.append((obs.current_stage(), obs.current_trace()))
+
+    tid = obs.new_trace()
+    with ThreadPoolExecutor(2) as pool:
+        with obs.span("plan", stage="preprocess", trace=tid):
+            pool.submit(obs.bind(work)).result()
+            pool.submit(work).result()
+        pool.submit(work).result()
+    assert seen == [("preprocess", tid), (None, None), (None, None)]
+    obs.set_level("off")
+    assert obs.bind(work) is work
+
+
+def test_compile_time_lands_on_the_callers_stage():
+    """A fresh jit compiled in a pool worker under a ``preprocess`` span
+    counts as ``compile.preprocess``; a jit nested in it counts once."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    import jax
+    import jax.numpy as jnp
+
+    obs.set_level("metrics")
+    assert obs.install_compile_listener()
+    stage = obs.REGISTRY.get("repro_stage_seconds")
+    hist = stage.labels(stage="compile.preprocess")
+    compiles = obs.REGISTRY.get("repro_engine_compiles_total")
+    s0, c0 = hist.sum, sum(c.value for c in compiles.children()
+                           if c.label_values[0] == "preprocess")
+
+    inner = jax.jit(lambda x: jnp.sort(x) * 3)
+
+    def outer(x):
+        return inner(x + 1).sum()
+
+    outer_j = jax.jit(outer)
+    with ThreadPoolExecutor(2) as pool:
+        with obs.span("plan", stage="preprocess") as sp:
+            futs = [pool.submit(obs.bind(lambda n=n: outer_j(
+                jnp.arange(n, dtype=jnp.float32)).block_until_ready()))
+                for n in (7, 9)]
+            for f in futs:
+                f.result()
+    added = hist.sum - s0
+    assert 0 < added <= sp.elapsed_s
+    assert sum(c.value for c in compiles.children()
+               if c.label_values[0] == "preprocess") >= c0 + 2
+    assert obs.current_stage() is None
+
+
+def test_samples_drawn_counter_replaces_the_rate_gauge():
+    assert obs.REGISTRY.get("repro_sampler_samples_per_s") is None
+    d0 = engine.STATS.samples_drawn
+    r = estimate(_graph(), get_motif("M4-2"), DELTA, 5 * CHUNK, seed=0,
+                 chunk=CHUNK)
+    assert engine.STATS.samples_drawn - d0 == r.k == 5 * CHUNK
+    assert "repro_engine_samples_drawn_total" in \
+        obs.REGISTRY.prometheus_text()
