@@ -101,6 +101,46 @@ def monotone_find(g, lo: jnp.ndarray, hi: jnp.ndarray, r: jnp.ndarray,
     return l
 
 
+def excluded_find(CL, CE, pair_pos: jnp.ndarray, plo: jnp.ndarray,
+                  phi: jnp.ndarray, qlo: jnp.ndarray, qhi: jnp.ndarray,
+                  r: jnp.ndarray, iters: int = ITERS) -> jnp.ndarray:
+    """Inverse CDF of ``g(p) = CL(p) - CE(cross(p))`` over ``[plo, phi)``.
+
+    ``cross(p)`` is the smallest ``k in [qlo, qhi]`` with
+    ``pair_pos[k] >= p``: the positions ``pair_pos[qlo:qhi]`` (strictly
+    increasing, inside ``[plo, phi)``) are excluded slots whose ``CE``
+    weight equals their ``CL`` weight, so each has effective weight 0.
+    Returns what ``monotone_find(g, plo, phi, r)`` returns, with two
+    bisections one after the other instead of one nested in the other:
+
+    A. ``kappa``: the smallest ``k in [qlo, qhi]`` with ``k == qhi`` or
+       ``g(pair_pos[k]) = CL(pair_pos[k]) - CE(k) > r``, so the answer
+       lies in the run of slots between ``pair_pos[kappa - 1]`` and
+       ``pair_pos[kappa]``, where ``cross`` is ``kappa``;
+    B. ``monotone_find`` of ``h(p) = CL(p) - CE(kappa)`` over the whole
+       ``[plo, phi)``: ``h`` is non-decreasing, equals ``g`` on that run
+       and its end, lies at or below ``g`` before it and above ``r`` after
+       it, so its one crossing of ``r`` is ``g``'s.  With the same bounds
+       as the nested search it also returns the same when ``g(phi) == 0``.
+    """
+    qlo, qhi = jnp.asarray(qlo), jnp.asarray(qhi)
+    nmax = pair_pos.shape[0] - 1
+
+    def body(_, c):
+        l, h = c
+        mid = (l + h) >> 1
+        pos = pair_pos[jnp.clip(mid, 0, nmax)].astype(qlo.dtype)
+        active = l < h
+        go_right = active & (CL(pos) - CE(mid) <= r)
+        l2 = jnp.where(go_right, mid + 1, l)
+        h2 = jnp.where(active & ~go_right, mid, h)
+        return (l2, h2)
+
+    kappa, _ = jax.lax.fori_loop(0, iters, body, (qlo, qhi))
+    ce = CE(kappa)
+    return monotone_find(lambda p: CL(p) - ce, plo, phi, r, iters=iters)
+
+
 @partial(jax.jit, static_argnames=("side",))
 def _ss(vals, targets, side):
     return jnp.searchsorted(vals, targets, side=side)

@@ -14,9 +14,11 @@ Pipeline per sample (all steps data-parallel over K):
    ``(i+1)*wd`` breakpoint) CDF over the window's contiguous edge-id range;
 3. children top-down (static tree schedule): candidate list =
    alpha-CSR segment of the meet vertex, window-truncated time bounds,
-   minus the parallel-edge pair list (Claim 4.8) — sampled by bisecting
-   ``g(p) = Lambda_prefix(p) - El_prefix(cross(p))`` where ``cross`` is a
-   nested bisection into the pair position sub-sequence.
+   minus the parallel-edge pair list (Claim 4.8) — the inverse CDF of
+   ``g(p) = Lambda_prefix(p) - El_prefix(cross(p))``, found by two
+   bisections in turn (``bisect.excluded_find``): one over the pair list
+   for the run between excluded slots that holds the target, then one
+   over the CSR range with that run's ``cross`` held fixed.
 
 The phases carry ``jax.named_scope`` names — ``sample/window``,
 ``sample/center``, ``sample/child`` (every bound, pair and inverse-CDF
@@ -35,8 +37,8 @@ ensure_x64()
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from .bisect import (converge_iters, monotone_find,  # noqa: E402
-                     seg_lower_bound, seg_upper_bound)
+from .bisect import (converge_iters, excluded_find,  # noqa: E402
+                     monotone_find, seg_lower_bound, seg_upper_bound)
 from .spanning_tree import BEFORE, OUT, SpanningTree  # noqa: E402
 
 
@@ -330,21 +332,19 @@ def _make_sample_fn_xla(tree: SpanningTree, K: int):
                                                         iters=it), qlo, qhi)
                         CE = _two_piece(wts.ps_pair_own[c],
                                         wts.ps_pair_prev[c], qlo, qmid)
-
-                        def g(p, CL=CL, CE=CE, pair_pos=pair_pos, qlo=qlo,
-                              qhi=qhi, it=it):
-                            cross = seg_lower_bound(pair_pos, qlo, qhi, p,
-                                                    iters=it)
-                            return CL(p) - CE(cross)
+                        # every pair slot in [qlo, qhi) lies in [plo, phi)
+                        Wx = CL(phi) - CE(qhi)
                     else:
-                        def g(p, CL=CL):
-                            return CL(p)
+                        Wx = CL(phi)
 
-                    Wx = g(phi)
                     rx = jax.random.randint(keys[2 + c], (K,), 0,
                                             jnp.maximum(Wx, 1),
                                             dtype=jnp.int64)
-                    pstar = monotone_find(g, plo, phi, rx, iters=it)
+                    if wts.use_c2:
+                        pstar = excluded_find(CL, CE, pair_pos, plo, phi,
+                                              qlo, qhi, rx, iters=it)
+                    else:
+                        pstar = monotone_find(CL, plo, phi, rx, iters=it)
                     edges[c] = csr_edge[pstar].astype(jnp.int64)
 
         E = jnp.stack(edges, axis=1)  # [K, S]

@@ -78,6 +78,34 @@ def test_pallas_sampler_bit_identical(graph, dev, motif_name, use_c2):
             f"kernel mismatch on {k}"
 
 
+@pytest.fixture(scope="module")
+def heavy_graph():
+    """Long pair lists (up to 79 edges): most edges repeat a pair within
+    a short span over two windows, so 85 of the 214 pair lists cross a
+    window breakpoint and the CSR lists around them carry equal stamps
+    of other pairs."""
+    return powerlaw_temporal_graph(n=40, m=1_500, time_span=9_000,
+                                   multiplicity=0.6, seed=11)
+
+
+@pytest.mark.parametrize("motif_name", ["M5-3", "M4-2"])
+def test_xla_sampler_matches_ref_heavy_pairs(heavy_graph, motif_name):
+    """XLA (two sequential C2 bisections) == the nested int64 ref."""
+    dev = heavy_graph.device_arrays()
+    motif = get_motif(motif_name)
+    tree = candidate_trees(motif, n_candidates=1, roots_per_tree=1)[0]
+    wts = preprocess(heavy_graph, tree, DELTA, dev=dev, use_c2=True)
+    assert int(wts.W_total) > 0
+    key = jax.random.PRNGKey(4)
+
+    s_xla = _make_sample_fn_xla(tree, K)(dev, wts, key)
+    x, uhi, ulo = prepare_draws(tree, wts, key, K)
+    s_ref = tree_sampler_ref(tree, dev, wts, x, uhi, ulo)
+    for k in ("edges", "window", "phi_v"):
+        assert (np.asarray(s_xla[k]) == np.asarray(s_ref[k])).all(), \
+            f"ref mismatch on {k}"
+
+
 def test_backend_seam_and_guarded_fallback(graph, dev, monkeypatch):
     """Env resolves the backend; the guarded fn falls back outside the
     kernel envelope (here: a zero VMEM budget) with identical samples."""
