@@ -7,7 +7,11 @@ bandwidth, over the device time of the window programs in the trace.
 Bytes: ``bench/workmodel.py``'s lower bound per sample on the served
 tree, times the samples of the traced window executions (one execution
 draws ``chunk * checkpoint_every`` samples per stream; the batch mix has
-one stream).  Memory-bound work, so bandwidth is the roofline."""
+one stream).  On a mesh every window runs once on each device, each
+drawing its share of the window's samples: the executions, summed over
+the devices, are divided by the devices, and the device seconds, summed
+likewise, count every chip's bandwidth.  Memory-bound work, so
+bandwidth is the roofline."""
 from bench.traffic import window_samples
 
 
@@ -17,7 +21,7 @@ def read(ctx):
         return None
     runs = [(n, s) for name, (n, s) in tr["modules"].items()
             if "window" in name]
-    n = sum(r[0] for r in runs)
+    n = sum(r[0] for r in runs) / tr["devices"]
     secs = sum(r[1] for r in runs)
     bps = ctx.check["info"]["bytes_per_sample"]
     if not n or secs <= 0 or not bps:

@@ -14,7 +14,9 @@ The cell (``BENCHMARK.json`` ``workloads``) names a configuration
    cached as ``bench/data/<config>.<seed>.npz``);
 2. starts the gateway, ``python -m repro.launch.estimate --serve
    --gateway``, as a child with ``JAX_PLATFORMS=tpu`` and the mix's
-   server flags; the parent never imports jax while the child lives;
+   server flags, and with ``--mesh <chips>`` where the cell asks for
+   more than one chip; the parent never imports jax while the child
+   lives;
 3. loads the graph into a stream tenant (``ingest``, one ``advance``),
    plans the standing pairs and
    warms every window shape the mix uses (one fused dispatch of 1 to
@@ -26,15 +28,19 @@ The cell (``BENCHMARK.json`` ``workloads``) names a configuration
    (``bench/check.py``) and prints one JSON line: the end-to-end metrics
    with ``--trace 0``; with ``--trace 1`` (telemetry at ``trace``, a
    profiler capture of the first engine windows) the per-layer metrics,
-   ``busy_s``/``window_s`` and a breakdown.
+   ``busy_s``/``window_s`` and a breakdown.  The window's answers go to
+   ``<out>/answers.json`` (``bench/out`` unless the caller names
+   another directory).
 
-No chip, or fewer chips than the cell asks for: exit 1, no result.
+No chip, fewer chips than the cell asks for, or a served mesh of another
+size: exit 1, no result.
 """
 from __future__ import annotations
 
 import argparse
 import importlib.util
 import json
+import math
 import os
 import shutil
 import signal
@@ -51,7 +57,8 @@ if ROOT not in sys.path:
 from bench import check as checker  # noqa: E402
 from bench import reference, synth, traffic  # noqa: E402
 from bench import trace as tracing  # noqa: E402
-from bench.server import Server, ServerError, child_env  # noqa: E402
+from bench.server import (Server, ServerError, child_env,  # noqa: E402
+                          served_mesh)
 
 #: whole-run cap for the child (the first run of a checkout compiles)
 RUN_DEADLINE_S = 1150.0
@@ -121,6 +128,7 @@ class Context:
     trace: dict | None
     check: dict
     peaks: dict | None = None
+    xplane: str | None = None       # the run's profiler capture
 
 
 def warm(server: Server, cell: Cell, seed: int) -> list:
@@ -181,22 +189,25 @@ def answers_of(window: traffic.Window) -> list:
     out = []
     for rec in window.records:
         r = rec.reply or {}
-        out.append(dict(motif=rec.req["motif"], delta=rec.req["delta"],
-                        k=r.get("k"), W=r.get("W"),
+        out.append(dict(id=rec.req["id"], motif=rec.req["motif"],
+                        delta=rec.req["delta"], k=r.get("k"), W=r.get("W"),
                         estimate=r.get("estimate"), failed=not rec.ok))
     return out
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
-             root: str = ROOT, platform: str = "tpu", alter=None,
-             grace: float = 60.0) -> dict:
+             root: str = ROOT, out_dir: str | None = None,
+             platform: str = "tpu", alter=None, grace: float = 60.0) -> dict:
     """One run; returns the result object (see the module docstring).
 
-    ``platform`` and ``alter`` are the seams the tests drive: the CPU
-    instead of the chip, and a function applied to each reply as the
-    clients receive it (a fault planted under the timed path)."""
+    ``out_dir`` (emptied first; default ``bench/out``) takes the run's
+    files: the server's log, the profile, the answers.  ``platform`` and
+    ``alter`` are the seams the tests drive: the CPU instead of the
+    chip, and a function applied to each reply as the clients receive it
+    (a fault planted under the timed path)."""
     t_start = time.monotonic()
-    out_dir = os.path.join(root, "bench", "out")     # the last run's files
+    if out_dir is None:
+        out_dir = os.path.join(root, "bench", "out")
     shutil.rmtree(out_dir, ignore_errors=True)
     gpath = synth.graph_path(os.path.join(root, "bench", "data"),
                              cell.config_name, cell.config["graph"], seed)
@@ -205,6 +216,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     srv = cell.mix["server"]
     flags = ["--chunk", str(srv["chunk"]),
              "--checkpoint-every", str(srv["checkpoint_every"])]
+    if cell.chips > 1:
+        flags += ["--mesh", str(cell.chips)]
     if trace:
         flags += ["--profile-dir", os.path.join(out_dir, "profile")]
     os.makedirs(out_dir)
@@ -224,6 +237,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         if dev["platform"] != platform or dev["count"] < cell.chips:
             raise BenchError(f"server computes on {dev}; the cell needs "
                              f"{cell.chips} {platform} device(s)")
+        mesh = served_mesh(server.wait_banner())
+        if (math.prod(mesh.values()) if mesh else 1) != cell.chips:
+            raise BenchError(f"server serves on mesh {mesh}; the cell "
+                             f"needs {cell.chips} chip(s)")
         t = time.monotonic()
         load_tenant(server, gpath, cell.config["graph"])
         parts["load_s"] = time.monotonic() - t
@@ -253,8 +270,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     compiles = _cache_entries(root) - cache_setup
     t = time.monotonic()
     g = reference.Graph(*synth.load(gpath))
-    verdict = checker.check(answers_of(window), cell.config, g, cell.limits,
-                            seed)
+    answers = answers_of(window)
+    with open(os.path.join(out_dir, "answers.json"), "w") as f:
+        json.dump(answers, f)
+    verdict = checker.check(answers, cell.config, g, cell.limits, seed)
     parts["check_s"] = time.monotonic() - t
     red = None
     if xplane is not None:
@@ -263,14 +282,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     ctx = Context(cell=cell, seconds=seconds, setup_s=setup_s,
                   setup_scrape=setup_scrape,
                   window=window, trace=red, check=verdict,
-                  peaks=peaks(dev["kind"]) if platform == "tpu" else None)
+                  peaks=peaks(dev["kind"]) if platform == "tpu" else None,
+                  xplane=xplane)
     metrics = {}
     for name, spec in cell.metrics.items():
         v = reader(name, root)(ctx)
         if v is not None:
             metrics[name] = {"value": v, "unit": spec["unit"]}
     device = {"platform": dev["platform"], "kind": dev["kind"],
-              "count": dev["count"], "memory_peak_bytes": dev["peak_bytes"]}
+              "count": dev["count"], "mesh": mesh,
+              "memory_peak_bytes": dev["peak_bytes"]}
     result = {"correct": verdict["correct"],
               "attempted": len(window.records),
               "failed": verdict["numbers"]["failed"][0],
@@ -285,7 +306,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         "compiles_in_window": compiles,
         "lru_misses_in_window": (m1["lru"].get("miss", 0)
                                  - m0["lru"].get("miss", 0)),
-        "client_late_s": window.late, **parts, "info": verdict["info"]}
+        "client_late_s": window.late,
+        "trace_devices": red["devices"] if red else None,
+        **parts, "info": verdict["info"]}
     result["check"] = {k: {"value": v, "limit": lim}
                        for k, (v, lim) in verdict["numbers"].items()}
     return result

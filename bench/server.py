@@ -8,9 +8,11 @@ Replies are routed to their callers by ``id`` (requests) or ``cmd``
 """
 from __future__ import annotations
 
+import ast
 import collections
 import json
 import os
+import re
 import subprocess
 import sys
 import threading
@@ -34,6 +36,7 @@ class Server:
             cwd=root, env=env, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
             stderr=subprocess.PIPE, text=True, bufsize=1)
         self.stderr: collections.deque = collections.deque(maxlen=200)
+        self.banner: str | None = None    # the child's "serving ..." line
         self._cv = threading.Condition()
         self._replies: dict = {}          # routing key -> list of replies
         self._closed = False
@@ -63,6 +66,10 @@ class Server:
     def _pump_err(self) -> None:
         for line in self.proc.stderr:
             self.stderr.append(line.rstrip("\n"))
+            if self.banner is None and line.startswith("serving "):
+                with self._cv:
+                    self.banner = line.rstrip("\n")
+                    self._cv.notify_all()
             if self._log is not None:
                 self._log.write(line)
 
@@ -103,6 +110,18 @@ class Server:
                     raise TimeoutError(f"no reply for {key} in time")
                 self._cv.wait(timeout=left)
 
+    def wait_banner(self, timeout: float = 30.0) -> str:
+        """The line the child prints when it starts serving (it names
+        the mesh it serves on); call once it has answered a verb."""
+        until = min(time.monotonic() + timeout, self.deadline)
+        with self._cv:
+            while self.banner is None:
+                left = until - time.monotonic()
+                if left <= 0:
+                    self.fail("server printed no 'serving' line")
+                self._cv.wait(timeout=left)
+            return self.banner
+
     def call(self, obj: dict) -> dict:
         """A control verb: send it and return its ``ok`` reply."""
         self.send(obj)
@@ -137,6 +156,15 @@ class Server:
             t.join(timeout=10)
         if self._log is not None:
             self._log.close()
+
+
+def served_mesh(banner: str) -> dict | None:
+    """The mesh a ``serving GATEWAY ... mesh={'data': 4} ...`` line
+    names, as ``{axis: size}``; None where it serves on no mesh."""
+    m = re.search(r"\bmesh=(None|\{[^}]*\})", banner)
+    if m is None:
+        raise ServerError(f"no mesh in the server's line {banner!r}")
+    return ast.literal_eval(m.group(1))
 
 
 def parse_metrics(text: str) -> dict:

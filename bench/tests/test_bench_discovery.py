@@ -52,3 +52,15 @@ def test_unknown_device_kind_has_no_peaks():
     assert peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
         peaks("TPU v9 imaginary")
+
+
+def test_each_pair_of_config_and_traffic_is_one_cell():
+    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    assert len(pairs) == len(set(pairs))
+
+
+def test_mesh_cell_serves_the_one_chip_mix():
+    # Only the chips differ, so samples_per_s reads scaling efficiency.
+    one, four = Cell.load("aml.batch", False), Cell.load("aml.batch.mesh4", False)
+    assert (one.chips, four.chips) == (1, 4)
+    assert one.mix == four.mix and one.config == four.config

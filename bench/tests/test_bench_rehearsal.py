@@ -3,7 +3,9 @@ run comes out correct, its last line has the result's documented shape,
 and each fault planted under the timed path turns ``correct`` false.
 
 The seams: ``platform="cpu"`` skips the harness's look for a chip, and
-``alter`` rewrites each reply as the clients receive it.
+``alter`` rewrites each reply as the clients receive it.  Every run
+writes to a directory of its own, so that runs in parallel test workers
+never share one.
 """
 import json
 import os
@@ -32,14 +34,15 @@ def _cell(trace=False):
                 metrics={m["name"]: m for m in pick})
 
 
-def _run(alter=None, trace=False, seed=424242):
-    return run_cell(_cell(trace), seed, SECONDS, trace, platform="cpu",
-                    alter=alter, grace=3.0)
+def _run(out_dir, alter=None, trace=False, seed=424242, cell=None):
+    return run_cell(cell or _cell(trace), seed, SECONDS, trace,
+                    out_dir=str(out_dir), platform="cpu", alter=alter,
+                    grace=3.0)
 
 
 @pytest.fixture(scope="module")
-def sound():
-    return _run()
+def sound(tmp_path_factory):
+    return _run(tmp_path_factory.mktemp("sound"))
 
 
 def test_sound_run_is_correct(sound):
@@ -53,9 +56,10 @@ def test_last_line_shape(sound):
     assert list(line)[:5] == ["correct", "attempted", "failed", "metrics",
                               "device"]
     assert list(line)[-1] == "check"
-    assert set(line["device"]) >= {"platform", "kind", "count",
+    assert set(line["device"]) >= {"platform", "kind", "count", "mesh",
                                    "memory_peak_bytes"}
     assert line["device"]["platform"] == "cpu"
+    assert line["device"]["mesh"] is None
     for name in _cell().metrics:
         m = line["metrics"][name]
         assert m["value"] > 0 and m["unit"]
@@ -63,8 +67,8 @@ def test_last_line_shape(sound):
         assert set(c) == {"value", "limit"}
 
 
-def test_traced_run_reports_per_layer_metrics():
-    r = _run(trace=True, seed=515151)
+def test_traced_run_reports_per_layer_metrics(tmp_path):
+    r = _run(tmp_path, trace=True, seed=515151)
     assert r["correct"], r["check"]
     assert r["metrics"]["preprocess_s"]["value"] > 0
     assert "setup_s" not in r["metrics"]
@@ -98,8 +102,8 @@ def _drop_every_second():
     (_bump_W, "w_gap"),                  # the DP's total altered
     (_drop_every_second(), "failed"),    # half the batch left out
 ])
-def test_planted_fault_is_not_correct(fault, number):
-    r = _run(alter=fault)
+def test_planted_fault_is_not_correct(fault, number, tmp_path):
+    r = _run(tmp_path, alter=fault)
     assert not r["correct"]
     c = r["check"][number]
     assert c["value"] > c["limit"], r["check"]

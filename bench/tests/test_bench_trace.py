@@ -69,3 +69,34 @@ def test_no_device_events_reads_nothing():
     ctx = SimpleNamespace(trace=None, peaks=None)
     assert reader("device_idle_pct.batch")(ctx) is None
     assert reader("window_roofline_pct")(ctx) is None
+
+
+def _device(windows):
+    ops = [("%fusion.1", s + 1, e - 1) for _, s, e in windows]
+    return {T.MODULES_LINE: list(windows), T.OPS_LINE: ops}
+
+
+def test_roofline_counts_each_window_once_on_a_mesh():
+    """Four devices that each run every window execution, on a quarter
+    of its samples in a quarter of the time, draw the windows' samples
+    once between them: the roofline share reads what one device running
+    the whole windows reads."""
+    from bench.peaks import peaks
+
+    def windows(scale):
+        return [("jit_window(1)", 0.0, 4e8 * scale),
+                ("jit_window(1)", 5e8, 5e8 + 4e8 * scale)]
+    ctx = SimpleNamespace(peaks=peaks("TPU v5 lite"),
+                          cell=SimpleNamespace(mix={"server": {
+                              "chunk": 8192, "checkpoint_every": 4}}),
+                          check={"info": {"bytes_per_sample":
+                                          {"M5-3": 200.0}}})
+    one = T.reduce_trace([("/device:TPU:0", _device(windows(1.0)))])
+    four = T.reduce_trace([(f"/device:TPU:{i}", _device(windows(0.25)))
+                           for i in range(4)])
+    assert (one["devices"], four["devices"]) == (1, 4)
+    roof = reader("window_roofline_pct")
+    r1 = roof(SimpleNamespace(trace=one, **vars(ctx)))
+    r4 = roof(SimpleNamespace(trace=four, **vars(ctx)))
+    assert r1 == pytest.approx(100 * 2 * 32768 * 200.0 / 819e9 / 0.8)
+    assert r4 == pytest.approx(r1)

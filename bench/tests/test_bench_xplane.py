@@ -20,7 +20,7 @@ SCOPELESS = os.path.join(DATA, "tpu_window_trace.pbtxt")
 # annotations with their stats
 SCOPED = os.path.join(DATA, "tpu_scoped_window_trace.pbtxt")
 TRACE_READERS = ("sampler_ns_per_sample", "validator_ns_per_sample",
-                 "window_gap_ms")
+                 "window_gap_ms", "window_allreduce_ms")
 COMPILE_READERS = ("preprocess_compile_s", "window_compile_s")
 
 
@@ -44,7 +44,10 @@ def test_scoped_capture_on_the_chip():
     # the drain's annotation on the profiler clock
     assert X.window_gaps_ns(planes) == pytest.approx(
         [6697497.5, 6421583.516])
+    # one chip: no all-reduce
+    assert X.allreduce_ns(planes) is None
     ctx = SimpleNamespace(xplane=SCOPED)
+    assert reader("window_allreduce_ms")(ctx) is None
     assert reader("sampler_ns_per_sample")(ctx) == pytest.approx(
         1232492.032 / 98304)
     assert reader("validator_ns_per_sample")(ctx) == pytest.approx(
@@ -129,3 +132,60 @@ def test_compile_readers():
     bare = SimpleNamespace(setup_scrape={"stage": {"preprocess": [30.0, 1.0]}})
     for name in COMPILE_READERS:
         assert reader(name)(bare) is None
+
+
+@pytest.mark.parametrize("name,code", [
+    ("%all-reduce.3 = s64[1,1]{1,0:T(1,128)} all-reduce(s64[1,1] %x), "
+     "replica_groups={{0,1,2,3}}", "all-reduce"),
+    # as the mesh window program compiles for a v5e: an int64 psum as
+    # one all-reduce of a tuple of u32 parts
+    ("%all-reduce.18 = (u32[1,1]{1,0:T(1,128)}, /*index=5*/u32[1,1]{1,0:"
+     "T(1,128)}) all-reduce(%psum.112, %psum.116), channel_id=1",
+     "all-reduce"),
+    ("%all-reduce-start.1 = (u32[2]{0}, u32[2]{0}) all-reduce-start("
+     "u32[2]{0:T(256)} %p)", "all-reduce-start"),
+    ("%fusion.12 = u32[8192]{0:T(1024)} fusion(u32[8192] %a), kind=kLoop",
+     "fusion"),
+    ("%all-reduce-done.4", "all-reduce-done"),
+    ("%fusion.7", "fusion"),
+])
+def test_opcode(name, code):
+    assert X.opcode(name) == code
+
+
+def _mesh_planes(devices=4):
+    """``devices`` devices, each running two windows with an all-reduce
+    at its end (split in two in the first, whole in the second, named
+    by its bare name or its text), and one all-reduce outside any
+    window."""
+    planes = []
+    for d in range(devices):
+        planes.append((f"/device:TPU:{d}", {
+            T.MODULES_LINE: [_ev("jit_window(1)", 0, 100),
+                             _ev("jit_window(1)", 110, 210),
+                             _ev("jit_sum(2)", 300, 320)],
+            T.OPS_LINE: [_ev("%while.1", 0, 90),
+                         _ev("%f.1", 1, 80),
+                         _ev("%all-reduce-start.1 = (u32[2]{0}) "
+                             "all-reduce-start(u32[2]{0} %p)", 91, 93),
+                         _ev("%all-reduce-done.1", 94, 99),
+                         _ev("%while.1", 110, 200),
+                         _ev("%f.1", 111, 190),
+                         _ev("%ar.9 = u32[2]{0} all-reduce(u32[2]{0} %q)",
+                             203, 209),
+                         _ev("%all-reduce.5", 301, 319)],
+        }))
+    return planes
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_allreduce_time_per_window_execution_per_device(devices,
+                                                        monkeypatch):
+    planes = _mesh_planes(devices)
+    # (2 + 5) ns in each first window, 6 in each second; the one in
+    # jit_sum is out
+    assert X.allreduce_ns(planes) == pytest.approx(13.0 / 2)
+    monkeypatch.setattr(X, "capture", lambda ctx, profile_dir: planes)
+    assert reader("window_allreduce_ms")(SimpleNamespace()) == \
+        pytest.approx(6.5e-6)
+    assert X.allreduce_ns(_planes()) is None

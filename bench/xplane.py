@@ -21,11 +21,14 @@ capture lacks the names):
 * ``dispatched_samples``: the ``samples`` stat summed over the
   ``engine.dispatch`` host annotations;
 * ``window_gaps_ns``: the device idle between consecutive window
-  executions whose gap lies inside one ``session.drain`` annotation.
+  executions whose gap lies inside one ``session.drain`` annotation;
+* ``allreduce_ns``: device time of the all-reduce ops inside the window
+  executions, per execution per device (a mesh's ``psum``).
 """
 from __future__ import annotations
 
 import os
+import re
 
 from bench import trace as tracing
 
@@ -35,6 +38,8 @@ PHASES = ("sample", "validate", "score")
 #: ``tf_op``)
 SCOPE_STATS = ("tf_op", "op_name")
 METADATA_PLANE = "/host:metadata"
+#: HLO opcodes of an all-reduce, synchronous or split in two
+ALLREDUCE_OPCODES = ("all-reduce", "all-reduce-start", "all-reduce-done")
 
 _CLASSES: dict = {}
 _MEMO: dict = {}
@@ -319,3 +324,33 @@ def window_gaps_ns(planes: list) -> list | None:
                   if s < s1 and e > e0)
         gaps.append(max(0.0, (s1 - e0) - ran))
     return gaps or None
+
+
+def opcode(name: str) -> str:
+    """An op event's HLO opcode, from its name: the instruction's text
+    as a TPU writes it (``%x = s64[2]{0} all-reduce(...)``: the first
+    lower-case word before a parenthesis after the ``=``), else the bare
+    instruction name less its number (``%all-reduce.3``)."""
+    if " = " in name:
+        m = re.search(r"(?<![\w-])([a-z][a-z0-9-]*)\(",
+                      name.split(" = ", 1)[1])
+        if m:
+            return m.group(1)
+    return re.sub(r"\.\d+$", "", tracing.op_name(name).lstrip("%"))
+
+
+def allreduce_ns(planes: list) -> float | None:
+    """Mean device ns, per window execution per device, of the innermost
+    ops with an all-reduce opcode (``ALLREDUCE_OPCODES``) inside the
+    window executions; None where there is none."""
+    total, runs, seen = 0.0, 0, False
+    for lines in _devices(planes):
+        wins = _windows(lines)
+        runs += len(wins)
+        ops = [(n, s, e) for n, s, e, _ in lines.get(tracing.OPS_LINE, [])
+               if any(a <= s and e <= b for a, b in wins)]
+        for n, s, e in tracing.innermost(ops):
+            if opcode(n) in ALLREDUCE_OPCODES:
+                seen = True
+                total += e - s
+    return total / runs if seen else None
