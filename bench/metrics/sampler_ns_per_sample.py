@@ -14,10 +14,4 @@ PROFILE = os.path.join(os.path.dirname(os.path.dirname(
 
 
 def read(ctx):
-    planes = xplane.capture(ctx, PROFILE)
-    if planes is None:
-        return None
-    ns, samples = xplane.phase_ns(planes), xplane.dispatched_samples(planes)
-    if not ns or not samples or not ns["sample"]:
-        return None
-    return ns["sample"] / samples
+    return xplane.ns_per_sample(ctx, PROFILE, ("sample",))

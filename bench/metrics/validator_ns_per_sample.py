@@ -17,10 +17,4 @@ PROFILE = os.path.join(os.path.dirname(os.path.dirname(
 
 
 def read(ctx):
-    planes = xplane.capture(ctx, PROFILE)
-    if planes is None:
-        return None
-    ns, samples = xplane.phase_ns(planes), xplane.dispatched_samples(planes)
-    if not ns or not samples or not (ns["validate"] + ns["score"]):
-        return None
-    return (ns["validate"] + ns["score"]) / samples
+    return xplane.ns_per_sample(ctx, PROFILE, ("validate", "score"))

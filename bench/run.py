@@ -20,7 +20,9 @@ The cell (``BENCHMARK.json`` ``workloads``) names a configuration
 3. loads the graph into a stream tenant (``ingest``, one ``advance``),
    plans the standing pairs and
    warms every window shape the mix uses (one fused dispatch of 1 to
-   ``clients`` request streams per motif) -- all of this is ``setup_s``;
+   ``clients`` request streams per motif; for a mix with a stated error,
+   bursts that state every target and run through their growth rounds)
+   -- all of this is ``setup_s``;
 4. drives the mix's closed-loop clients for ``--seconds``, awaits the
    requests still out, reads the device's peak memory and stops the
    child;
@@ -127,6 +129,7 @@ class Context:
     window: traffic.Window
     trace: dict | None
     check: dict
+    window_scrapes: tuple = ()      # the scrapes before and after it
     peaks: dict | None = None
     xplane: str | None = None       # the run's profiler capture
 
@@ -136,27 +139,59 @@ def warm(server: Server, cell: Cell, seed: int) -> list:
     for each motif, one dispatch of J fused request streams, J = 1 ..
     clients (a burst of J requests written at once lands in one drain;
     ``fused_jobs`` in the replies confirms it, else the burst repeats).
+
+    With a stated error the burst's requests take the mix's targets in
+    turn, so that each (motif, target) runs, at the mix's initial ``k``
+    and ``k_max``, and each runs to its answer: its growth rounds fuse
+    the J - 1 .. 1 streams still short of their targets.  The replies'
+    ``fused_jobs`` is then the last round's, so the ``drain`` stage's
+    count confirms that the burst took one drain.
     Returns ``[motif, J, seconds, fused]`` per burst."""
-    k = traffic.window_samples(cell.mix)
+    targets = cell.mix.get("target_rse")
+    k = int(cell.mix["k"]) if targets else traffic.window_samples(cell.mix)
     n, log = 0, []
     for motif, delta in cell.config["standing"]:
+        turn = 0
         for J in range(1, int(cell.mix["clients"]) + 1):
             for _ in range(4):
-                burst = [dict(tenant=TENANT, id=f"warm.{n + j}", motif=motif,
-                              delta=int(delta), k=k,
-                              seed=traffic._mix(seed, 9, n + j))
-                         for j in range(J)]
+                burst = []
+                for j in range(J):
+                    target = (targets[(turn + j) % len(targets)]
+                              if targets else None)
+                    burst.append(dict(
+                        tenant=TENANT, id=f"warm.{n + j}",
+                        **traffic.stated(motif, delta, k,
+                                         traffic._mix(seed, 9, n + j),
+                                         target, cell.mix)))
                 n += J
+                drains = _drains(server) if targets else 0
                 t = server.send(*burst)
                 replies = [server.wait(("id", b["id"]))[1] for b in burst]
                 bad = [r for r in replies if r.get("ok") is not True]
                 if bad:
                     server.fail(f"warm-up request failed: {bad[0]}")
-                fused = all(r.get("fused_jobs") == J for r in replies)
+                if targets:
+                    fused = _drains(server) - drains == 1
+                else:
+                    fused = all(r.get("fused_jobs") == J for r in replies)
                 log.append([motif, J, time.monotonic() - t, fused])
+                _progress(f"warm {motif} J={J} {log[-1][2]:.1f} s "
+                          f"k={[r.get('k') for r in replies]} fused={fused}")
                 if fused:
+                    turn += J
                     break
     return log
+
+
+def _progress(what: str) -> None:
+    """A progress line on stderr, so that a run cut by its deadline
+    shows where its time went."""
+    print(f"bench: {what}", file=sys.stderr, flush=True)
+
+
+def _drains(server: Server) -> float:
+    """Session drains the server has run (the ``drain`` stage's count)."""
+    return server.stages()["stage"].get("drain", [0.0, 0.0])[1]
 
 
 def load_tenant(server: Server, gpath: str, graph: dict) -> None:
@@ -176,43 +211,55 @@ def load_tenant(server: Server, gpath: str, graph: dict) -> None:
                          f"configuration n={graph['n']} m={graph['m']}")
 
 
-def _cache_entries(root: str) -> int:
+def _cache_entries(cache_dir: str) -> int:
     """Programs in the persistent compile cache (one ``*-cache`` file
     per compiled program)."""
-    d = os.path.join(root, "bench", "cache", "jax")
-    return (sum(1 for f in os.listdir(d) if f.endswith("-cache"))
-            if os.path.isdir(d) else 0)
+    return (sum(1 for f in os.listdir(cache_dir) if f.endswith("-cache"))
+            if os.path.isdir(cache_dir) else 0)
 
 
 def answers_of(window: traffic.Window) -> list:
-    """The window's requests as the check reads them."""
+    """The window's requests as the check reads them, with their send and
+    answer times in seconds from the window's start; a request that
+    states an error carries its stated ``target_rse`` (as the mix states
+    it, not as it was sent) and the ``rse`` its reply reports."""
     out = []
     for rec in window.records:
         r = rec.reply or {}
-        out.append(dict(id=rec.req["id"], motif=rec.req["motif"],
-                        delta=rec.req["delta"], k=r.get("k"), W=r.get("W"),
-                        estimate=r.get("estimate"), failed=not rec.ok))
+        a = dict(id=rec.req["id"], motif=rec.req["motif"],
+                 delta=rec.req["delta"], k=r.get("k"), W=r.get("W"),
+                 estimate=r.get("estimate"), failed=not rec.ok,
+                 sent=rec.sent - window.t0,
+                 answered=(None if rec.answered is None
+                           else rec.answered - window.t0))
+        if "target_rse" in rec.req:
+            a.update(target_rse=rec.req["target_rse"], rse=r.get("rse"))
+        out.append(a)
     return out
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
              root: str = ROOT, out_dir: str | None = None,
-             platform: str = "tpu", alter=None, grace: float = 60.0) -> dict:
+             platform: str = "tpu", alter=None, grace: float = 60.0,
+             cache_dir: str | None = None) -> dict:
     """One run; returns the result object (see the module docstring).
 
     ``out_dir`` (emptied first; default ``bench/out``) takes the run's
-    files: the server's log, the profile, the answers.  ``platform`` and
-    ``alter`` are the seams the tests drive: the CPU instead of the
-    chip, and a function applied to each reply as the clients receive it
-    (a fault planted under the timed path)."""
+    files: the server's log, the profile, the answers.  ``cache_dir``
+    (default ``bench/cache/jax``) is the child's persistent compile
+    cache.  ``platform`` and ``alter`` are the seams the tests drive:
+    the CPU instead of the chip, and a function applied to each reply as
+    the clients receive it (a fault planted under the timed path)."""
     t_start = time.monotonic()
     if out_dir is None:
         out_dir = os.path.join(root, "bench", "out")
+    if cache_dir is None:
+        cache_dir = os.path.join(root, "bench", "cache", "jax")
     shutil.rmtree(out_dir, ignore_errors=True)
     gpath = synth.graph_path(os.path.join(root, "bench", "data"),
                              cell.config_name, cell.config["graph"], seed)
     parts = {"graph_s": time.monotonic() - t_start}
-    cache_start = _cache_entries(root)
+    cache_start = _cache_entries(cache_dir)
     srv = cell.mix["server"]
     flags = ["--chunk", str(srv["chunk"]),
              "--checkpoint-every", str(srv["checkpoint_every"])]
@@ -222,7 +269,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         flags += ["--profile-dir", os.path.join(out_dir, "profile")]
     os.makedirs(out_dir)
     server = Server(root, flags, child_env(root, platform,
-                                           "trace" if trace else "metrics"),
+                                           "trace" if trace else "metrics",
+                                           cache_dir),
                     deadline=t_start + RUN_DEADLINE_S,
                     log=os.path.join(out_dir, "server.err"))
     if alter is not None:
@@ -244,11 +292,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         t = time.monotonic()
         load_tenant(server, gpath, cell.config["graph"])
         parts["load_s"] = time.monotonic() - t
+        _progress(f"graph and server up at {t - t_start:.1f} s, graph "
+                  f"loaded in {parts['load_s']:.1f} s")
         t = time.monotonic()
         parts["warm"] = warm(server, cell, seed)
         parts["warm_s"] = time.monotonic() - t
         setup_scrape = server.stages()
-        cache_setup = _cache_entries(root)
+        cache_setup = _cache_entries(cache_dir)
+        _progress(f"set-up done at {time.monotonic() - t_start:.1f} s: "
+                  + ", ".join(f"{k} {v[0]:.1f} s" for k, v in
+                              sorted(setup_scrape["stage"].items())))
         if trace:
             server.call({"cmd": "profile",
                          "windows": int(cell.mix["profile_windows"])})
@@ -267,7 +320,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         raise BenchError(f"{type(e).__name__}: {e}") from e
     finally:
         server.kill()
-    compiles = _cache_entries(root) - cache_setup
+    compiles = _cache_entries(cache_dir) - cache_setup
     t = time.monotonic()
     g = reference.Graph(*synth.load(gpath))
     answers = answers_of(window)
@@ -282,6 +335,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     ctx = Context(cell=cell, seconds=seconds, setup_s=setup_s,
                   setup_scrape=setup_scrape,
                   window=window, trace=red, check=verdict,
+                  window_scrapes=(m0, m1),
                   peaks=peaks(dev["kind"]) if platform == "tpu" else None,
                   xplane=xplane)
     metrics = {}
@@ -306,6 +360,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         "compiles_in_window": compiles,
         "lru_misses_in_window": (m1["lru"].get("miss", 0)
                                  - m0["lru"].get("miss", 0)),
+        "backend_compiles_in_window": m1["compiles"] - m0["compiles"],
         "client_late_s": window.late,
         "trace_devices": red["devices"] if red else None,
         **parts, "info": verdict["info"]}

@@ -169,9 +169,11 @@ def served_mesh(banner: str) -> dict | None:
 
 def parse_metrics(text: str) -> dict:
     """Prometheus text -> ``{"stage": {name: [sum_s, count]},
-    "lru": {event: count}}`` for the series the benchmark reads."""
+    "lru": {event: count}, "compiles": backend compiles}`` for the
+    series the benchmark reads."""
     stage: dict = {}
     lru: dict = {}
+    compiles = 0.0
     for line in text.splitlines():
         for part, slot in (("_sum", 0), ("_count", 1)):
             head = f'repro_stage_seconds{part}{{stage="'
@@ -182,16 +184,19 @@ def parse_metrics(text: str) -> dict:
         head = 'repro_engine_window_lru_total{cache="window",event="'
         if line.startswith(head):
             lru[line[len(head):].split('"', 1)[0]] = float(line.split()[-1])
-    return {"stage": stage, "lru": lru}
+        if line.startswith("repro_engine_compiles_total{"):
+            compiles += float(line.split()[-1])
+    return {"stage": stage, "lru": lru, "compiles": compiles}
 
 
-def child_env(root: str, platform: str, obs_level: str) -> dict:
+def child_env(root: str, platform: str, obs_level: str,
+              cache_dir: str) -> dict:
     """The child's environment: the chip (no CPU fallback), telemetry
-    level, the program on the path, and the benchmark's fixed compile
-    cache inside the checkout.  ``LIBTPU_INIT_ARGS`` passes untouched."""
+    level, the program on the path, and the compile cache ``cache_dir``
+    (the benchmark's fixed one inside the checkout, or a CPU test's
+    own).  ``LIBTPU_INIT_ARGS`` passes untouched."""
     env = dict(os.environ, JAX_PLATFORMS=platform, REPRO_OBS=obs_level,
-               JAX_COMPILATION_CACHE_DIR=os.path.join(root, "bench", "cache",
-                                                      "jax"))
+               JAX_COMPILATION_CACHE_DIR=cache_dir)
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(root, "src"), os.environ.get("PYTHONPATH"))
         if p)

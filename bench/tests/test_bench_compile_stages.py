@@ -8,8 +8,8 @@ TRACE_READERS = ("sampler_ns_per_sample", "validator_ns_per_sample",
                  "window_gap_ms")
 
 
-def test_traced_run_reports_compile_stages(tmp_path):
-    r = _run(tmp_path, trace=True, seed=616161)
+def test_traced_run_reports_compile_stages(tmp_path, jax_cache):
+    r = _run(tmp_path, jax_cache, trace=True, seed=616161)
     assert r["correct"], r["check"]
     pre = r["metrics"]["preprocess_compile_s"]["value"]
     assert 0 < pre <= r["metrics"]["preprocess_s"]["value"]

@@ -28,7 +28,7 @@ def _answers(out_dir) -> dict:
                 if not a["failed"]}
 
 
-def _mesh_run(out_dir, chips, shim=None):
+def _mesh_run(out_dir, cache, chips, shim=None):
     """A run of the tiny cell asking for ``chips`` chips, the child seeing
     four devices (and loading ``shim`` as its ``sitecustomize``)."""
     with pytest.MonkeyPatch.context() as mp:
@@ -42,12 +42,14 @@ def _mesh_run(out_dir, chips, shim=None):
             mp.setenv("PYTHONPATH", os.pathsep.join(
                 p for p in (shim_dir, os.environ.get("PYTHONPATH")) if p))
         cell = dataclasses.replace(_cell(), chips=chips)
-        return _run(os.path.join(out_dir, "run"), seed=SEED, cell=cell)
+        return _run(os.path.join(out_dir, "run"), cache, seed=SEED,
+                    cell=cell)
 
 
-def test_four_chip_cell_serves_on_a_mesh_with_the_same_answers(tmp_path):
-    one = _run(tmp_path / "one", seed=SEED)
-    four = _mesh_run(str(tmp_path / "four"), 4)
+def test_four_chip_cell_serves_on_a_mesh_with_the_same_answers(tmp_path,
+                                                               jax_cache):
+    one = _run(tmp_path / "one", jax_cache, seed=SEED)
+    four = _mesh_run(str(tmp_path / "four"), jax_cache, 4)
     assert one["correct"] and four["correct"], (one["check"], four["check"])
     assert one["device"]["mesh"] is None
     assert four["device"]["mesh"] == {"data": 4}
@@ -59,13 +61,14 @@ def test_four_chip_cell_serves_on_a_mesh_with_the_same_answers(tmp_path):
     assert {i: a4[i] for i in common} == {i: a1[i] for i in common}
 
 
-def test_more_chips_than_the_server_sees_gives_no_result(tmp_path):
+def test_more_chips_than_the_server_sees_gives_no_result(tmp_path,
+                                                         jax_cache):
     with pytest.raises(BenchError):
-        _mesh_run(str(tmp_path), 8)
+        _mesh_run(str(tmp_path), jax_cache, 8)
 
 
-def test_exchange_left_out_is_not_correct(tmp_path):
-    r = _mesh_run(str(tmp_path), 4, shim=NO_PSUM)
+def test_exchange_left_out_is_not_correct(tmp_path, jax_cache):
+    r = _mesh_run(str(tmp_path), jax_cache, 4, shim=NO_PSUM)
     assert r["device"]["mesh"] == {"data": 4}
     assert not r["correct"]
     c = r["check"]["z_max"]

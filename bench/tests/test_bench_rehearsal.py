@@ -5,7 +5,7 @@ and each fault planted under the timed path turns ``correct`` false.
 The seams: ``platform="cpu"`` skips the harness's look for a chip, and
 ``alter`` rewrites each reply as the clients receive it.  Every run
 writes to a directory of its own, so that runs in parallel test workers
-never share one.
+never share one, and compiles into the tests' own cache (``jax_cache``).
 """
 import json
 import os
@@ -34,15 +34,16 @@ def _cell(trace=False):
                 metrics={m["name"]: m for m in pick})
 
 
-def _run(out_dir, alter=None, trace=False, seed=424242, cell=None):
-    return run_cell(cell or _cell(trace), seed, SECONDS, trace,
+def _run(out_dir, cache, alter=None, trace=False, seed=424242, cell=None,
+         seconds=SECONDS):
+    return run_cell(cell or _cell(trace), seed, seconds, trace,
                     out_dir=str(out_dir), platform="cpu", alter=alter,
-                    grace=3.0)
+                    grace=3.0, cache_dir=cache)
 
 
 @pytest.fixture(scope="module")
-def sound(tmp_path_factory):
-    return _run(tmp_path_factory.mktemp("sound"))
+def sound(tmp_path_factory, jax_cache):
+    return _run(tmp_path_factory.mktemp("sound"), jax_cache)
 
 
 def test_sound_run_is_correct(sound):
@@ -67,8 +68,8 @@ def test_last_line_shape(sound):
         assert set(c) == {"value", "limit"}
 
 
-def test_traced_run_reports_per_layer_metrics(tmp_path):
-    r = _run(tmp_path, trace=True, seed=515151)
+def test_traced_run_reports_per_layer_metrics(tmp_path, jax_cache):
+    r = _run(tmp_path, jax_cache, trace=True, seed=515151)
     assert r["correct"], r["check"]
     assert r["metrics"]["preprocess_s"]["value"] > 0
     assert "setup_s" not in r["metrics"]
@@ -102,8 +103,8 @@ def _drop_every_second():
     (_bump_W, "w_gap"),                  # the DP's total altered
     (_drop_every_second(), "failed"),    # half the batch left out
 ])
-def test_planted_fault_is_not_correct(fault, number, tmp_path):
-    r = _run(tmp_path, alter=fault)
+def test_planted_fault_is_not_correct(fault, number, tmp_path, jax_cache):
+    r = _run(tmp_path, jax_cache, alter=fault)
     assert not r["correct"]
     c = r["check"][number]
     assert c["value"] > c["limit"], r["check"]

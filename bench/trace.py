@@ -139,3 +139,10 @@ def _host_activity(host: list, a: float, b: float) -> str:
         if s <= mid <= e and (best is None or e - s < best[2] - best[1]):
             best = (name, s, e)
     return best[0] if best else "no host event"
+
+
+def idle_pct(tr: dict | None) -> float | None:
+    """1 - busy / window of a reduced capture, in %; None without one."""
+    if not tr or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])

@@ -5,11 +5,17 @@ A mix (``bench/traffic/<mix>.json``) states:
 * ``clients``: closed-loop clients; each sends its next request when the
   last one is answered;
 * ``k``: the fixed sample budget of every request;
+* ``target_rse`` (optional, a list) and ``k_max``: screen traffic.  Every
+  request then states a target relative error and a budget cap, and
+  ``k`` is its initial budget, which the server grows until the target
+  is met or ``k_max`` is reached;
 * ``server``: the gateway's ``chunk`` and ``checkpoint_every``;
 * ``profile_windows``: engine windows the traced run captures.
 
 Requests cycle through the configuration's standing (motif, delta)
-pairs; each carries a fresh seed derived from the run's seed.
+pairs, and with ``target_rse`` through every (pair, target) combination,
+the motif varying first; each carries a fresh seed derived from the
+run's seed.
 """
 from __future__ import annotations
 
@@ -31,16 +37,33 @@ def window_samples(mix: dict) -> int:
     return int(mix["server"]["chunk"]) * int(mix["server"]["checkpoint_every"])
 
 
+def stated(motif: str, delta: int, k: int, seed: int, target,
+           mix: dict) -> dict:
+    """One request's fields; a stated error adds ``target_rse`` and the
+    mix's ``k_max``."""
+    req = dict(motif=motif, delta=int(delta), k=int(k), seed=seed)
+    if target is not None:
+        req.update(target_rse=float(target), k_max=int(mix["k_max"]))
+    return req
+
+
 class Requests:
     """The deterministic request sequence of each client."""
 
     def __init__(self, mix: dict, standing: list, seed: int):
-        self.mix, self.standing, self.seed = mix, standing, int(seed)
+        self.mix, self.seed = mix, int(seed)
+        # (motif, delta, target), the motif varying first; no target
+        # where the mix states none
+        self.combos = [(motif, int(delta), t)
+                       for t in mix.get("target_rse") or [None]
+                       for motif, delta in standing]
 
     def request(self, client: int, i: int) -> dict:
-        motif, delta = self.standing[(client + i) % len(self.standing)]
-        return dict(id=f"c{client}.{i}", motif=motif, delta=int(delta),
-                    k=int(self.mix["k"]), seed=_mix(self.seed, 0, client, i))
+        motif, delta, target = self.combos[(client + i) % len(self.combos)]
+        return dict(id=f"c{client}.{i}",
+                    **stated(motif, delta, self.mix["k"],
+                             _mix(self.seed, 0, client, i), target,
+                             self.mix))
 
 
 @dataclass
@@ -55,6 +78,15 @@ class Record:
     def ok(self) -> bool:
         r = self.reply
         return bool(r and r.get("ok") is True and not r.get("degraded"))
+
+    @property
+    def met(self) -> bool:
+        """Answered, and within its stated error where it states one:
+        the ``rse`` it reports is at most its ``target_rse``."""
+        target = self.req.get("target_rse")
+        rse = self.reply.get("rse") if self.ok else None
+        return self.ok and (target is None
+                            or (rse is not None and rse <= target))
 
 
 @dataclass

@@ -294,6 +294,20 @@ def dispatched_samples(planes: list) -> int | None:
     return total or None
 
 
+def ns_per_sample(ctx, profile_dir: str, phases: tuple) -> float | None:
+    """Device ns of the innermost ops under ``phases`` in the captured
+    window executions over the samples on the captured
+    ``engine.dispatch`` annotations; None where the capture has no such
+    scope or annotation."""
+    planes = capture(ctx, profile_dir)
+    if planes is None:
+        return None
+    ns, samples = phase_ns(planes), dispatched_samples(planes)
+    if not ns or not samples or not sum(ns[p] for p in phases):
+        return None
+    return sum(ns[p] for p in phases) / samples
+
+
 def _host_events(planes: list, wanted: str) -> list:
     return [(s, e) for n, ls in planes if not tracing.is_device(n)
             for evs in ls.values() for name, s, e, _ in evs
