@@ -122,6 +122,11 @@ def excluded_find(CL, CE, pair_pos: jnp.ndarray, plo: jnp.ndarray,
        and its end, lies at or below ``g`` before it and above ``r`` after
        it, so its one crossing of ``r`` is ``g``'s.  With the same bounds
        as the nested search it also returns the same when ``g(phi) == 0``.
+
+    ``CL`` and ``CE`` are the sampler's one-gather probes
+    (``core.sampler._two_piece``: their loop-invariant lookups are done
+    when they are built), so a step of phase A gathers three times
+    (``pair_pos``, ``CL``, ``CE``) and a step of phase B once.
     """
     qlo, qhi = jnp.asarray(qlo), jnp.asarray(qhi)
     nmax = pair_pos.shape[0] - 1

@@ -12,6 +12,8 @@ Pipeline per sample (all steps data-parallel over K):
 1. window  ``i  ~  W_i / W``          — bisect the window-prefix CDF;
 2. center  ``e0 ~  w_{c,e} / W_i``    — two-piece (own|prev split at the
    ``(i+1)*wd`` breakpoint) CDF over the window's contiguous edge-id range;
+   each probe of a two-piece CDF is ONE gather from the own|prev table,
+   its offsets gathered once before the bisection (``_two_piece``);
 3. children top-down (static tree schedule): candidate list =
    alpha-CSR segment of the meet vertex, window-truncated time bounds,
    minus the parallel-edge pair list (Claim 4.8) — the inverse CDF of
@@ -73,10 +75,22 @@ def _two_piece(ps_own, ps_prev, lo, mid):
 
     ``C(p) = (PSo[min(p,mid)] - PSo[lo]) + (PSp[max(p,mid)] - PSp[mid])``;
     positions < mid are in their own window, >= mid in their prev window.
+    Only one lookup depends on ``p``: ``PSo[p]`` below ``mid``, ``PSp[p]``
+    from it on.  So the three others are gathered here, once, into the
+    offsets of the two pieces, and ``C(p)`` is one gather from the table
+    ``[PSo | PSp]`` plus its piece's offset.  int64 wraps, so the
+    regrouped sums are the same integers.
     """
+    n = ps_own.shape[-1]
+    table = jnp.concatenate([ps_own, ps_prev])
+    base = ps_own[lo]
+    off_own = -base
+    off_prev = ps_own[mid] - base - ps_prev[mid]
+
     def C(p):
-        return ((ps_own[jnp.minimum(p, mid)] - ps_own[lo])
-                + (ps_prev[jnp.maximum(p, mid)] - ps_prev[mid]))
+        own = p < mid
+        return (table[jnp.where(own, p, p + n)]
+                + jnp.where(own, off_own, off_prev))
     return C
 
 
@@ -275,7 +289,7 @@ def _make_sample_fn_xla(tree: SpanningTree, K: int):
             mid = wts.win_mid[win]
             hi = wts.win_hi[win]
             Cc = _two_piece(wts.ps_acc_own[r], wts.ps_acc_prev[r], lo, mid)
-            e0 = monotone_find(lambda p: Cc(p), lo, hi, resid, iters=it)
+            e0 = monotone_find(Cc, lo, hi, resid, iters=it)
 
         edges = [None] * S
         edges[r] = e0

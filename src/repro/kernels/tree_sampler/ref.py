@@ -11,9 +11,20 @@ import jax.numpy as jnp
 
 from ...core.bisect import (converge_iters, monotone_find, seg_lower_bound,
                             seg_upper_bound)
-from ...core.sampler import _two_piece, bisect_iters
+from ...core.sampler import bisect_iters
 from ...core.spanning_tree import BEFORE, OUT, SpanningTree
 from .kernel import randint_from_bits
+
+
+def _two_piece(ps_own, ps_prev, lo, mid):
+    """Cumulative-in-window weight, four lookups a probe:
+    ``C(p) = (PSo[min(p,mid)] - PSo[lo]) + (PSp[max(p,mid)] - PSp[mid])``
+    (the oracle keeps its own copy of the formula, independent of the
+    sampler's one-gather form)."""
+    def C(p):
+        return ((ps_own[jnp.minimum(p, mid)] - ps_own[lo])
+                + (ps_prev[jnp.maximum(p, mid)] - ps_prev[mid]))
+    return C
 
 
 def tree_sampler_ref(tree: SpanningTree, dev, wts, x, uhi, ulo):

@@ -5,6 +5,9 @@ CE(cross(p))`` over a CSR range ``[plo, phi)`` whose excluded pair slots
 ``pair_pos[qlo:qhi]`` carry zero effective weight.  The nested form runs
 ``seg_lower_bound`` for ``cross`` inside every step of ``monotone_find``;
 ``excluded_find`` must return the same position for every target.
+
+Both searches probe ``_two_piece``'s one-gather CDF, which must give the
+four-lookup sum it folds, to the integer, wherever it is probed.
 """
 from __future__ import annotations
 
@@ -112,3 +115,60 @@ def test_excluded_find_matches_nested(name):
         if W:
             # every drawn position carries positive effective weight
             assert (g_hi > g_lo).all()
+
+
+def _prefixes(values, n, rng):
+    """Two int64 rows of length ``n + 1`` (own, prev); ``near_2_62`` starts
+    at 2^62 and climbs past 2^63, so the sums wrap."""
+    if values == "small":
+        return [np.concatenate([[0], np.cumsum(rng.integers(0, 4, n))])
+                for _ in range(2)]
+    if values == "near_2_62":
+        return [(1 << 62) + np.concatenate(
+            [[0], np.cumsum(rng.integers(1 << 59, 1 << 60, n))])
+            for _ in range(2)]
+    info = np.iinfo(np.int64)
+    return [rng.integers(info.min, info.max, n + 1, dtype=np.int64,
+                         endpoint=True) for _ in range(2)]
+
+
+def _four_gather(own, prev, lo, mid, p):
+    """``(PSo[min(p,mid)] - PSo[lo]) + (PSp[max(p,mid)] - PSp[mid])`` in
+    numpy int64, which wraps as the device does."""
+    return ((own[np.minimum(p, mid)] - own[lo])
+            + (prev[np.maximum(p, mid)] - prev[mid]))
+
+
+N = 9
+#: ``(lo, mid, hi)`` of one scalar window each; ``exhaustive`` takes every
+#: ``lo <= mid <= hi`` in ``[0, N]`` as lanes of one batch
+LAYOUTS = {
+    "interior": (2, 5, 8),
+    "lo_eq_mid": (3, 3, 7),
+    "mid_eq_hi": (1, 6, 6),
+    "empty": (4, 4, 4),
+    "whole": (0, 4, N),
+}
+
+
+@pytest.mark.parametrize("values", ["small", "near_2_62", "full_range"])
+@pytest.mark.parametrize("layout", [*LAYOUTS, "exhaustive"])
+def test_two_piece_one_gather_matches_four(layout, values):
+    """The folded one-gather ``_two_piece`` gives the four-gather sum, to
+    the integer, at every ``p`` in ``[lo, hi]`` (``p == mid`` included)."""
+    with np.errstate(over="ignore"):
+        own, prev = _prefixes(values, N, np.random.default_rng(11))
+        if layout == "exhaustive":
+            lanes = np.array([(lo, mid, p)
+                              for lo in range(N + 1)
+                              for mid in range(lo, N + 1)
+                              for hi in range(mid, N + 1)
+                              for p in range(lo, hi + 1)], np.int64).T
+            lo, mid, p = lanes
+        else:
+            lo, mid, hi = LAYOUTS[layout]
+            p = np.arange(lo, hi + 1, dtype=np.int64)
+        want = _four_gather(own, prev, lo, mid, p)
+    C = _two_piece(jnp.asarray(own), jnp.asarray(prev), jnp.asarray(lo),
+                   jnp.asarray(mid))
+    np.testing.assert_array_equal(np.asarray(C(jnp.asarray(p))), want)
