@@ -66,7 +66,7 @@ Each estimate stays bit-identical to its solo run (the shared stream's
 keys derive from ``(seed, chunk)`` alone — lint rule
 ``det-cohort-key``); to PIN a cohort rather than rely on per-graph
 min-W selection, pass the same rooted structure explicitly via
-``Request(tree=..., wts=...)`` (see benchmarks/run.py multimotif).
+``Request(tree=..., wts=...)``.
 
 Key objects
 -----------
@@ -109,13 +109,6 @@ are thin wrappers that build a one-shot ``Session`` per call —
 bit-identical to their pre-session behavior (pinned by
 tests/test_api.py golden values).  New code should hold a ``Session``.
 
-Serving
--------
-``python -m repro.launch.estimate --graph ... --serve`` wraps a session
-in a line-delimited-JSON stdin/stdout loop (see api/serve.py for the
-wire protocol) so one persistent process serves many queries against a
-resident graph.
-
 Live graphs
 -----------
 A ``Session`` holds one immutable snapshot.  For edge STREAMS — ingest
@@ -123,26 +116,21 @@ continuously, keep standing motif estimates fresh over a sliding window
 — use ``repro.stream.StreamingSession``, which swaps a fresh session
 onto each epoch snapshot while compiled window programs and preprocess
 traces carry over (power-of-two padded snapshots keep array shapes
-stable).  The serve loop grows matching ``ingest``/``advance``/
-``subscribe`` verbs (``--serve --stream``); each per-epoch standing
-count is bit-identical to a cold ``estimate()`` on that epoch's
-snapshot.
+stable).  Each per-epoch standing count is bit-identical to a cold
+``estimate()`` on that epoch's snapshot.
 
-Gateway (many tenants, one process)
------------------------------------
-Both serve modes above are single-graph and synchronous.  The
-production front door is ``repro.gateway`` (``--serve --gateway``):
-many independent graph/stream tenants pooled in one process behind a
-fair single-dispatcher scheduler — request intake and response emit
-overlap running drains, per-tenant quotas shed overload with the
-structured ``overloaded`` error kind, and ``Request(witnesses=n)``
-streams up to ``n`` accepted full-match edge tuples (a deterministic
-device-side reservoir) alongside each count.  See the
-``repro.gateway`` package docstring for the canonical usage guide.
+Serving
+-------
+This package holds no wire code.  The NDJSON front door is
+``repro.gateway`` (``python -m repro.launch.estimate --serve``): one
+loop that pools graph and stream tenants in one process behind a fair
+single-dispatcher scheduler.  ``--serve`` adopts a ``Session`` on
+``--graph``, ``--serve --stream`` a ``StreamingSession``, and ``--serve
+--gateway`` starts with an empty pool; tenants open over the wire.  See
+the ``repro.gateway`` package docstring for the canonical usage guide.
 """
 from .config import EstimateConfig
-from .serve import serve_loop
 from .session import Handle, Progress, Request, Session, SessionStats
 
 __all__ = ["EstimateConfig", "Handle", "Progress", "Request", "Session",
-           "SessionStats", "serve_loop"]
+           "SessionStats"]

@@ -10,8 +10,7 @@ restartable: chunk ``j`` always uses ``fold_in(base_key, j)``, so a
 checkpoint of ``(chunks_done, accumulators)`` resumes bit-identically
 after a failure — on any mesh shape (see the engine's determinism
 contract).  This module keeps ``choose_tree`` (Alg. 7, used directly by
-benchmarks/tree sweeps), the fused single-chunk micro-benchmark fn and
-the ``EstimateResult`` container.
+benchmarks/tree sweeps) and the ``EstimateResult`` container.
 """
 from __future__ import annotations
 
@@ -23,9 +22,7 @@ ensure_x64()
 
 from .graph import TemporalGraph  # noqa: E402
 from .motif import TemporalMotif  # noqa: E402
-from .sampler import make_sample_fn  # noqa: E402
 from .spanning_tree import SpanningTree, candidate_trees  # noqa: E402
-from .validate import make_count_fn  # noqa: E402
 from .weights import Weights, preprocess  # noqa: E402
 
 _ACC_KEYS = ("cnt2", "valid", "fail_vmap", "fail_delta", "fail_order",
@@ -45,37 +42,6 @@ def unbias_estimate(W: int, cnt2_sum: int, k: int) -> float:
     motif's solo run at the same budget.
     """
     return W * cnt2_sum / (2.0 * k) if k else 0.0
-
-
-def make_chunk_fn(tree: SpanningTree, chunk: int, Lmax: int = 16,
-                  sampler_backend: str | None = None):
-    """Fused sample->validate->count->reduce for one chunk (one dispatch).
-
-    Fusing the two jits (a) removes one host dispatch per chunk and (b)
-    lets XLA dead-code the [K, S] sample arrays straight into the DP
-    instead of materializing them between calls; the chunk reduces to six
-    scalars on device, so host<->device traffic per chunk is O(1)
-    (section Perf, estimator iteration C2).  Kept as the single-chunk
-    micro-benchmark unit; production windows dispatch through
-    ``engine.cached_window_fn``.
-
-    ``sampler_backend`` ("xla" | "pallas") picks the sampling path
-    *unguarded* (the fn is jitted, so the host-side eligibility check
-    cannot run inside) — callers gate with
-    ``tree_sampler.ops.pallas_sampler_eligible`` first, as the engine
-    does.
-    """
-    import jax as _jax
-
-    s_fn = make_sample_fn(tree, chunk, backend=sampler_backend, guard=False)
-    c_fn = make_count_fn(tree, chunk, Lmax=Lmax)
-
-    def fn(dev, wts, key):  # jit-of-jit inlines cleanly
-
-        samples = s_fn(dev, wts, key)
-        out = c_fn(dev, wts, samples)
-        return {k: out[k].sum() for k in _ACC_KEYS}
-    return _jax.jit(fn)
 
 
 @dataclass

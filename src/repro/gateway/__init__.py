@@ -1,6 +1,8 @@
 """Async gateway: overlapped drains, multi-graph tenancy, witness streaming.
 
-One process, many independent graphs/streams.  The gateway layers over
+One process, many independent graphs/streams, and the only NDJSON loop:
+every ``--serve`` mode runs ``gateway_serve_loop``, and the wire format
+lives in ``gateway/wire.py``.  The gateway layers over
 ``api.Session`` / ``stream.StreamingSession`` and adds exactly three
 things — everything below it (sampling keys, counts, estimates) is
 untouched, so gateway results are bit-identical to solo synchronous
@@ -25,8 +27,8 @@ untouched, so gateway results are bit-identical to solo synchronous
   streamed per checkpoint window over the wire and exposed on
   ``EstimateResult.witnesses``.
 
-Canonical usage — the wire loop (``launch/estimate.py --serve
---gateway``) or directly::
+Canonical usage — the wire loop (``launch/estimate.py --serve``) or
+directly::
 
     import io
     from repro.gateway import gateway_serve_loop
@@ -41,6 +43,13 @@ Canonical usage — the wire loop (``launch/estimate.py --serve
     ]) + "\\n"
     out = io.StringIO()
     gateway_serve_loop(infile=io.StringIO(lines), outfile=out)
+
+A session built by the caller joins the pool as tenant ``"default"``,
+and lines without ``"tenant"`` go to it (the CLI's ``--serve`` and
+``--serve --stream``; the caller keeps ownership and closes it)::
+
+    with Session(g, EstimateConfig(chunk=2048)) as s:
+        gateway_serve_loop(adopt=s)   # {"id": 1, "motif": "M5-3", ...}
 
 or in-process, scripting the same pieces the loop wires up::
 

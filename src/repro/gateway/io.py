@@ -1,15 +1,11 @@
 """Gateway I/O primitives: deadline line reader + threaded NDJSON emitter.
 
-:class:`LineSource` is the select-based line reader the serve loops use
-for coalescing-window timeouts (extracted from ``api/serve.py``, which
-now imports it from here).  It fixes the expired-deadline edge the old
-``_LineSource`` had: with ``timeout=0`` (or a deadline that passed while
-the caller was busy draining) the old reader returned ``None`` before
-ever consulting the fd — a complete line already sitting in the OS pipe
-buffer was invisible until the next blocking call.  This reader always
-runs at least one zero-wait ``select``/drain pass first, so buffered
-complete lines are returned even at an expired deadline, and a client
-trickling bytes still cannot hold the caller past its total deadline.
+:class:`LineSource` is the select-based line reader with total-deadline
+timeouts.  With ``timeout=0`` (or a deadline that passed while the
+caller was busy draining) it still runs one zero-wait ``select``/drain
+pass first, so a complete line already sitting in the OS pipe buffer is
+returned instead of a timeout, and a client trickling bytes still
+cannot hold the caller past its total deadline.
 
 :class:`Emitter` owns the response stream on its own thread: responses
 queue and the thread writes them, so a slow or stalled client blocks

@@ -1,9 +1,10 @@
-"""Gateway NDJSON loop: multi-tenant serving with overlapped drains.
+"""The NDJSON serving loop: multi-tenant serving with overlapped drains.
 
-``launch/estimate.py --serve --gateway`` exposes one process that pools
-MANY independent graphs/streams (tenants) and overlaps request intake,
-response emit and engine drains — the multi-tenant big sibling of
-``api.serve.serve_loop``.
+``launch/estimate.py --serve`` runs this loop in every mode: one process
+that pools independent graphs/streams (tenants) and overlaps request
+intake, response emit and engine drains.  ``--serve`` and ``--serve
+--stream`` build one session from their flags and adopt it as tenant
+``"default"``; ``--serve --gateway`` starts with an empty pool.
 
 Threads (see ``gateway.scheduler`` for why exactly these three):
 
@@ -17,8 +18,10 @@ Threads (see ``gateway.scheduler`` for why exactly these three):
 * **emitter**: writes responses; a stalled client blocks only this
   thread (``gateway.io.Emitter``).
 
-Wire verbs (one JSON object per line; all tenant-touching lines carry
-``"tenant": <name>``)::
+Wire verbs (one JSON object per line; tenant-touching lines carry
+``"tenant": <name>``, which defaults to ``"default"`` when the loop
+adopted a session; request and response fields are in
+``gateway/wire.py``)::
 
     {"cmd": "open_tenant", "tenant": "fin", "graph": "fintxn:n=1000,..."}
     {"cmd": "open_tenant", "tenant": "soc", "stream": true,
@@ -74,9 +77,10 @@ from .. import obs
 from ..api.config import EstimateConfig
 from ..resilience import OVERLOADED, OverloadedError, error_payload
 from ..resilience.retry import STATS as RSTATS
+from . import wire
 from .io import Emitter, LineSource
 from .scheduler import FairScheduler, Work
-from .state import GatewayState, Tenant
+from .state import DEFAULT_TENANT, GatewayState, Tenant
 
 #: engine.STATS counters summed per tenant (ints only — ratios are
 #: recomputed, never delta'd)
@@ -102,17 +106,6 @@ _TENANT_ADV = obs.REGISTRY.histogram(
 def _engine_snapshot() -> dict:
     from ..core.engine import STATS as ESTATS
     return {k: int(getattr(ESTATS, k)) for k in _ENGINE_COUNTERS}
-
-
-def _progress_line(rid, tenant: str, p) -> dict:
-    """One per-checkpoint-window witness line (emitted before the final
-    response, oldest window first)."""
-    import math
-    return dict(id=rid, tenant=tenant, progress=True, window=p.window,
-                k_done=p.k_done, estimate=p.estimate,
-                rse=None if math.isinf(p.rse) else p.rse,
-                witnesses=[dict(edges=[list(e) for e in w["edges"]],
-                                cnt=w["cnt"]) for w in (p.witnesses or ())])
 
 
 class _Gateway:
@@ -150,7 +143,6 @@ class _Gateway:
 
     def _do_requests(self, batch: list[Work]) -> None:
         """One fused coalescing window for one tenant's request burst."""
-        from ..api.serve import _parse_request, _response
         from ..core.motif import get_motif
 
         tenant = self.state.tenants.get(batch[0].tenant)
@@ -164,7 +156,7 @@ class _Gateway:
                     raise ValueError(
                         f"tenant {batch[0].tenant!r} closed before its "
                         "queued request executed")
-                req = _parse_request(
+                req = wire.parse_request(
                     {k: v for k, v in w.obj.items() if k != "tenant"})
                 if isinstance(req.motif, str):
                     get_motif(req.motif)   # fail THIS line, not the window
@@ -191,8 +183,8 @@ class _Gateway:
                 with obs.trace_context(w.trace):
                     if h.request.witnesses:
                         for p in h._progress:
-                            self.emit(_progress_line(rid, tenant.name, p))
-                    d = _response(rid, h)   # carries the final witnesses
+                            self.emit(wire.progress_line(rid, tenant.name, p))
+                    d = wire.response(rid, h)   # carries the final witnesses
                     d["tenant"] = tenant.name
                     if d.get("degraded"):
                         tenant.stats.degraded += 1
@@ -261,10 +253,9 @@ class _Gateway:
         return tenant
 
     def _do_ingest(self, w: Work) -> None:
-        from ..api.serve import _parse_ingest
         try:
             tenant = self._stream_of(w)
-            src, dst, t = _parse_ingest(
+            src, dst, t = wire.parse_ingest(
                 {k: v for k, v in w.obj.items() if k != "tenant"})
             n_in = tenant.stream.ingest(src, dst, t)
             self.emit(dict(ok=True, cmd="ingest", tenant=tenant.name,
@@ -275,7 +266,6 @@ class _Gateway:
                       error_payload(e))
 
     def _do_advance(self, w: Work) -> None:
-        from ..api.serve import _sub_response
         name = w.obj.get("tenant")
         try:
             tenant = self._stream_of(w)
@@ -286,8 +276,8 @@ class _Gateway:
                 for qid in sorted(er.results):
                     res, q = er.results[qid], queries[qid]
                     # a standing query's witnesses stream per epoch — the
-                    # reservoir rides its subscription line (_sub_response)
-                    d = _sub_response(qid, q, er.epoch.index, res)
+                    # reservoir rides its subscription line (wire.sub_response)
+                    d = wire.sub_response(qid, q, er.epoch.index, res)
                     d["tenant"] = tenant.name
                     self.emit(d)
                     tenant.stats.served += 1
@@ -309,12 +299,11 @@ class _Gateway:
             self._err(dict(cmd="advance", tenant=name), error_payload(e))
 
     def _do_subscribe(self, w: Work) -> None:
-        from ..api.serve import _SUBSCRIBE_FIELDS
         from ..stream import StandingQuery
         obj, name = w.obj, w.obj.get("tenant")
         try:
             tenant = self._stream_of(w)
-            allowed = _SUBSCRIBE_FIELDS | {"tenant"}
+            allowed = wire.SUBSCRIBE_FIELDS | {"tenant"}
             unknown = set(obj) - allowed
             if unknown:
                 raise ValueError(
@@ -358,7 +347,6 @@ class _Gateway:
         self.emit(dict(**head, ok=False, **payload))
 
     def health(self) -> dict:
-        from ..api.serve import device_block
         s = self.sched.stats
         return dict(
             ok=True, cmd="health", mode="gateway", served=self.served,
@@ -369,8 +357,8 @@ class _Gateway:
                            exec_failures=s.exec_failures,
                            quota=self.sched.quota),
             evictions=self.state.evictions,
-            resilience=RSTATS.as_dict(), engine=self._engine_block(),
-            obs=obs.summary(), device=device_block())
+            resilience=RSTATS.as_dict(), engine=wire.engine_stats(),
+            obs=obs.summary(), device=wire.device_block())
 
     def stats(self) -> dict:
         d = self.health()
@@ -378,33 +366,37 @@ class _Gateway:
         d["max_tenants"] = self.state.max_tenants
         return d
 
-    def _engine_block(self) -> dict:
-        from ..api.serve import _engine_stats
-        return _engine_stats()
-
 
 def gateway_serve_loop(config: EstimateConfig | None = None,
                        infile: IO = None, outfile: IO = None, *,
                        max_tenants: int = 8, quota: int = 16,
                        wal_dir: str | None = None, mesh=None,
-                       profile_dir: str | None = None) -> int:
+                       profile_dir: str | None = None,
+                       adopt=None) -> int:
     """Run the gateway NDJSON loop until EOF or ``quit``.
 
     Returns the number of estimation responses served (standing-query
     epoch responses included).  ``config`` applies to every tenant
-    opened; ``quota`` is the per-tenant pending-work cap (the
-    backpressure quota); ``wal_dir`` enables ``"wal": true`` stream
+    opened over the wire; ``quota`` is the per-tenant pending-work cap
+    (the backpressure quota); ``wal_dir`` enables ``"wal": true`` stream
     tenants (WAL file paths derive from it server-side — never from the
     wire); ``profile_dir`` enables the ``profile`` verb (profiler
     output paths are server-side only, like WAL paths).
+
+    ``adopt`` (an ``api.Session`` or ``stream.StreamingSession``) is
+    pooled as tenant ``"default"`` before the first line is read, and
+    lines without ``"tenant"`` go to it.  It stays the caller's to
+    close: the loop closes only the tenants it opened.
     """
-    from ..api.serve import _metrics, _profile, _trace_export
     cfg = (config or EstimateConfig()).resolve()
     src = LineSource(sys.stdin if infile is None else infile)
     gw = _Gateway(cfg, sys.stdout if outfile is None else outfile,
                   max_tenants=max_tenants, quota=quota, wal_dir=wal_dir,
                   mesh=mesh)
+    default = None
     try:
+        if adopt is not None:
+            default = gw.state.adopt(DEFAULT_TENANT, adopt).name
         while True:
             line = src.readline(None)
             if line == "":                       # EOF: drain-all, exit
@@ -430,11 +422,11 @@ def gateway_serve_loop(config: EstimateConfig | None = None,
                 # inline: a probe never waits on — or forces — a drain
                 gw.emit(gw.health() if cmd == "health" else gw.stats())
             elif cmd == "metrics":
-                gw.emit(_metrics())
+                gw.emit(wire.metrics())
             elif cmd == "trace":
-                gw.emit(_trace_export())
+                gw.emit(wire.trace_export())
             elif cmd == "profile":
-                gw.emit(_profile(obj, profile_dir))
+                gw.emit(wire.profile(obj, profile_dir))
             elif cmd == "open_tenant":
                 tid = obs.new_trace() if obs.enabled(obs.TRACE) else None
                 with obs.trace_context(tid), \
@@ -444,6 +436,8 @@ def gateway_serve_loop(config: EstimateConfig | None = None,
             elif cmd in ("close_tenant", "ingest", "advance", "subscribe",
                          "unsubscribe") or cmd is None:
                 kind = cmd or "request"
+                if obj.get("tenant") is None and default is not None:
+                    obj["tenant"] = default
                 name = obj.get("tenant")
                 head = dict(cmd=cmd) if cmd else dict(id=obj.get("id"))
                 head["tenant"] = name

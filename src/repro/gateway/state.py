@@ -4,7 +4,9 @@ A :class:`Tenant` is one resident workload — either a frozen graph
 behind an ``api.Session`` ("graph" mode) or a live edge stream behind a
 ``stream.StreamingSession`` ("stream" mode) — plus its FIFO work queue
 and serving counters.  :class:`GatewayState` pools them under the wire
-names ``open_tenant``/``close_tenant`` route on.
+names ``open_tenant``/``close_tenant`` route on; ``adopt`` pools a
+session the caller built (the CLI's ``--serve`` graph or ``--serve
+--stream`` stream) as the tenant that tenant-less lines go to.
 
 Why pooling pays: the engine's compiled-window-program LRU keys on the
 spanning tree (a pure function of the motif — ``SpanningTree`` is a
@@ -13,8 +15,7 @@ backend — never on graph identity — and jax's per-program executable
 cache keys on array *shapes*.  Stream tenants present power-of-two
 padded snapshot buckets and graph tenants of like size coincide
 naturally, so tenant N+1 on same-bucket shapes re-hits tenant N's
-compiled programs: its marginal cold-cost is preprocessing alone
-(``benchmarks/run.py --suite gateway`` measures this).
+compiled programs: its marginal cold-cost is preprocessing alone.
 
 Eviction: ``open_tenant`` past ``max_tenants`` evicts the
 least-recently-active IDLE tenant (empty queue — work in flight is
@@ -45,6 +46,9 @@ from ..stream import StreamingSession, StreamStore
 #: wire tenant names: path-safe, no traversal, bounded length
 _NAME_RE = re.compile(r"[A-Za-z0-9][A-Za-z0-9._-]{0,63}$")
 
+#: the name an adopted session pools under
+DEFAULT_TENANT = "default"
+
 
 @dataclass
 class TenantStats:
@@ -68,16 +72,19 @@ class Tenant:
 
     The work queue lives in the scheduler (keyed by NAME, so intake can
     enqueue for a tenant whose ``open_tenant`` is still in flight);
-    this object is the dispatch-time resolution target.
+    this object is the dispatch-time resolution target.  ``owned`` is
+    False for an adopted session: leaving the pool never closes it.
     """
 
     def __init__(self, name: str, mode: str, *, session: Session = None,
-                 stream: StreamingSession = None, wal_path: str = None):
+                 stream: StreamingSession = None, wal_path: str = None,
+                 owned: bool = True):
         self.name = name
         self.mode = mode                   # "graph" | "stream"
         self.session = session
         self.stream = stream
         self.wal_path = wal_path
+        self.owned = owned
         self.stats = TenantStats()
         self.opened_t = obs.monotonic()
         self.last_active = self.opened_t
@@ -91,6 +98,8 @@ class Tenant:
         self.last_active = obs.monotonic()
 
     def close(self) -> None:
+        if not self.owned:
+            return
         if self.mode == "graph":
             self.session.close()
         else:
@@ -164,12 +173,7 @@ class GatewayState:
         from it when one exists — a re-opened tenant resumes its stream
         bit-identically.
         """
-        if not isinstance(name, str) or not _NAME_RE.match(name):
-            raise BadRequestError(
-                f"bad tenant name {name!r}: want [A-Za-z0-9][A-Za-z0-9._-]*"
-                " (<= 64 chars)")
-        if name in self.tenants:
-            raise BadRequestError(f"tenant {name!r} is already open")
+        self._check_name(name)
         if (graph is None) == (not stream):
             raise BadRequestError(
                 'open_tenant needs exactly one of "graph": "<spec>" or '
@@ -204,6 +208,33 @@ class GatewayState:
                             session=Session(g, self.config, mesh=self.mesh))
         self.tenants[name] = tenant
         return tenant
+
+    def adopt(self, name: str, resident) -> Tenant:
+        """Pool an already-built ``Session`` or ``StreamingSession`` as
+        tenant ``name``, under the same capacity rules as
+        ``open_tenant``.  The caller keeps ownership: eviction, close
+        and ``close_all`` drop it from the pool without closing it.
+        Called before serving starts, so it races no dispatcher work."""
+        self._check_name(name)
+        if isinstance(resident, StreamingSession):
+            tenant = Tenant(name, "stream", stream=resident, owned=False)
+        elif isinstance(resident, Session):
+            tenant = Tenant(name, "graph", session=resident, owned=False)
+        else:
+            raise TypeError(f"adopt needs a Session or StreamingSession, "
+                            f"got {type(resident).__name__}")
+        if len(self.tenants) >= self.max_tenants:
+            self._evict_one()
+        self.tenants[name] = tenant
+        return tenant
+
+    def _check_name(self, name) -> None:
+        if not isinstance(name, str) or not _NAME_RE.match(name):
+            raise BadRequestError(
+                f"bad tenant name {name!r}: want [A-Za-z0-9][A-Za-z0-9._-]*"
+                " (<= 64 chars)")
+        if name in self.tenants:
+            raise BadRequestError(f"tenant {name!r} is already open")
 
     def close_tenant(self, name: str) -> Tenant:
         tenant = self.get(name)

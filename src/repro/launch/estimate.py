@@ -21,12 +21,16 @@ devices first, so a laptop can rehearse the 8-way layout:
         --graph powerlaw:n=2000,m=40000 --motif M5-3 --delta 5000 \
         --k 1048576 --devices 8 --mesh auto
 
-Serving mode — ``--serve`` keeps ONE resident session (graph upload,
-preprocess cache, compiled window programs) alive and answers
-line-delimited-JSON requests on stdin with JSON responses on stdout
-(wire protocol: ``repro.api.serve``).  Requests arriving within the
-coalescing window fuse like ``estimate_many`` jobs; ``target_rse``
-requests grow their budget adaptively:
+Serving mode — ``--serve`` answers line-delimited-JSON requests on
+stdin with JSON responses on stdout through the gateway loop
+(``repro.gateway``; wire format in ``gateway/wire.py``), which keeps
+resident sessions (graph upload, preprocess cache, compiled window
+programs) alive between requests.  The flags choose the tenant the loop
+adopts as ``"default"``, which lines without ``"tenant"`` go to:
+``--serve`` builds a session on ``--graph``, ``--serve --stream`` a live
+stream, and ``--serve --gateway`` adopts nothing (tenants open over the
+wire).  A tenant's consecutive requests fuse like ``estimate_many``
+jobs; ``target_rse`` requests grow their budget adaptively:
 
     printf '%s\\n' '{"id":1,"motif":"M5-3","delta":5000,"k":65536}' \\
                    '{"id":2,"motif":"0-1,1-2,2-0","delta":5000,"k":65536}' \\
@@ -36,10 +40,10 @@ requests grow their budget adaptively:
 ``--motif`` (and serve requests) accept inline edge-list specs like
 ``0-1,1-2,2-0`` (directed edges in pi order) besides catalog names.
 
-Streaming mode — ``--serve --stream`` starts with an EMPTY live graph
+Streaming mode — ``--serve --stream`` adopts an EMPTY live graph
 (``repro.stream``): clients ingest edge batches, advance epoch
 snapshots, and register standing queries over NDJSON (``{"cmd":
-"ingest" | "advance" | "subscribe"}``; protocol in ``repro.api.serve``).
+"ingest" | "advance" | "subscribe"}``; protocol in ``repro.gateway``).
 ``--horizon`` sets the sliding retention window.  Offline,
 ``--stream-replay FILE`` replays a recorded edge list (text/.gz/.npz)
 through the same machinery: each ``--replay-batch`` edges ingest as one
@@ -132,33 +136,33 @@ def main() -> None:
                     help="also run the exact oracle (slow!)")
     ap.add_argument("--serve", action="store_true",
                     help="persistent serving: answer line-delimited-JSON "
-                         "requests on stdin against one resident session "
-                         "(see repro.api.serve for the protocol)")
+                         "requests on stdin through the gateway loop, "
+                         "with a session on --graph as the default "
+                         "tenant (see repro.gateway for the protocol)")
     ap.add_argument("--coalesce-window", type=float, default=0.05,
                     help="serve: seconds a submit window stays open so "
                          "concurrent requests can fuse")
     ap.add_argument("--coalesce-max", type=int, default=64,
                     help="serve: max requests per submit window")
     ap.add_argument("--stream", action="store_true",
-                    help="with --serve: start on an EMPTY live graph and "
-                         "accept ingest/advance/subscribe verbs "
-                         "(repro.stream; --graph is ignored)")
+                    help="with --serve: the default tenant is an EMPTY "
+                         "live graph that accepts ingest/advance/subscribe "
+                         "verbs (repro.stream; --graph is ignored)")
     ap.add_argument("--gateway", action="store_true",
-                    help="with --serve: multi-tenant gateway — pool many "
-                         "graphs/streams in one process behind "
-                         "open_tenant/close_tenant verbs with overlapped "
-                         "drains (repro.gateway; --graph is ignored, "
-                         "tenants open over the wire)")
+                    help="with --serve: adopt no default tenant — start "
+                         "with an empty pool and open graph/stream "
+                         "tenants over the wire (--graph is ignored)")
     ap.add_argument("--max-tenants", type=int, default=8,
-                    help="gateway: tenant pool capacity (idle-LRU "
+                    help="serve: tenant pool capacity (idle-LRU "
                          "eviction past it)")
     ap.add_argument("--tenant-quota", type=int, default=16,
-                    help="gateway: max pending work items per tenant; "
+                    help="serve: max pending work items per tenant; "
                          "submits past it answer error_kind=overloaded")
     ap.add_argument("--wal-dir", default=None, metavar="DIR",
-                    help="gateway: directory for per-tenant WAL files "
-                         "(enables '\"wal\": true' stream tenants; paths "
-                         "derive from the tenant name server-side)")
+                    help="serve: directory for per-tenant WAL files "
+                         "(enables '\"wal\": true' stream tenants opened "
+                         "over the wire; paths derive from the tenant "
+                         "name server-side)")
     ap.add_argument("--stream-replay", default=None, metavar="FILE",
                     help="replay an edge-list file (text/.gz/.npz) as a "
                          "live stream: ingest in batches, advance epochs, "
@@ -198,12 +202,9 @@ def main() -> None:
                  "ingest/advance history)")
     if args.gateway and not args.serve:
         ap.error("--gateway requires --serve (it is a serving mode)")
-    if args.gateway and args.stream:
-        ap.error("--gateway pools graph AND stream tenants itself; open "
-                 "stream tenants over the wire instead of --stream")
-    if args.wal_dir is not None and not args.gateway:
-        ap.error("--wal-dir only applies to --serve --gateway (single-"
-                 "stream serving uses --wal PATH)")
+    if args.wal_dir is not None and not args.serve:
+        ap.error("--wal-dir requires --serve (it holds the WAL files of "
+                 "stream tenants opened over the wire)")
     if args.profile_dir is not None and not args.serve:
         ap.error("--profile-dir requires --serve (the 'profile' verb "
                  "arms the profiler over the wire)")
@@ -236,59 +237,55 @@ def main() -> None:
 
     mesh = build_mesh(args.mesh)
 
-    if args.serve and args.gateway:
+    if args.serve:
         import sys
 
-        from ..api import EstimateConfig
-        from ..api.serve import device_block
+        from ..api import EstimateConfig, Session
         from ..gateway import gateway_serve_loop
+        from ..gateway.wire import device_block
+        from ..stream import StreamingSession, StreamStore
         cfg = EstimateConfig(chunk=args.chunk, seed=args.seed,
                              checkpoint_every=args.checkpoint_every,
                              coalesce_window_s=args.coalesce_window,
                              coalesce_max_requests=args.coalesce_max,
                              sampler_backend=args.sampler_backend,
                              depsum_backend=args.depsum_backend)
+        # the default tenant: a live stream, nothing, or --graph
+        if args.stream:
+            if args.wal is not None:
+                store = StreamStore.recover(args.wal, horizon=args.horizon)
+                print(f"WAL {args.wal}: recovered epoch={store.epoch} "
+                      f"buffered={store.buffered} "
+                      f"ingested={store.stats.ingested}",
+                      file=sys.stderr, flush=True)
+            else:
+                store = StreamStore(horizon=args.horizon)
+            adopt = StreamingSession(store=store, config=cfg, mesh=mesh)
+            what = f"stream horizon={args.horizon} wal={args.wal}"
+        elif args.gateway:
+            adopt = None
+        else:
+            g = parse_graph(args.graph)
+            adopt = Session(g, cfg, mesh=mesh)
+            what = f"graph n={g.n} m={g.m} span={g.time_span}"
         dv = device_block()
+        # stdout is the response stream — logs go to stderr
         print(f"serving GATEWAY  max_tenants={args.max_tenants}  "
               f"quota={args.tenant_quota}  wal_dir={args.wal_dir}  "
               f"mesh={dict(mesh.shape) if mesh is not None else None}  "
               f"device={dv['platform']}:{dv['kind']}x{dv['count']}",
               file=sys.stderr, flush=True)
-        served = gateway_serve_loop(cfg, max_tenants=args.max_tenants,
-                                    quota=args.tenant_quota,
-                                    wal_dir=args.wal_dir, mesh=mesh,
-                                    profile_dir=args.profile_dir)
-        print(f"served {served} responses", file=sys.stderr)
-        return
-
-    if args.serve and args.stream:
-        import sys
-
-        from ..api import EstimateConfig, serve_loop
-        from ..stream import StreamingSession
-        cfg = EstimateConfig(chunk=args.chunk, seed=args.seed,
-                             checkpoint_every=args.checkpoint_every,
-                             coalesce_window_s=args.coalesce_window,
-                             coalesce_max_requests=args.coalesce_max,
-                             sampler_backend=args.sampler_backend,
-                             depsum_backend=args.depsum_backend)
-        if args.wal is not None:
-            from ..stream import StreamStore
-            store = StreamStore.recover(args.wal, horizon=args.horizon)
-            print(f"WAL {args.wal}: recovered epoch={store.epoch} "
-                  f"buffered={store.buffered} "
-                  f"ingested={store.stats.ingested}",
-                  file=sys.stderr, flush=True)
-            ss_kw = dict(store=store)
-        else:
-            ss_kw = dict(horizon=args.horizon)
-        with StreamingSession(config=cfg, mesh=mesh, **ss_kw) as ss:
-            print(f"serving LIVE stream  horizon={args.horizon}  "
-                  f"wal={args.wal}  "
-                  f"mesh={mesh.shape if mesh is not None else None}",
-                  file=sys.stderr, flush=True)
-            served = serve_loop(None, stream=ss,
-                                profile_dir=args.profile_dir)
+        if adopt is not None:
+            print(f"default tenant: {what}", file=sys.stderr, flush=True)
+        try:
+            served = gateway_serve_loop(cfg, max_tenants=args.max_tenants,
+                                        quota=args.tenant_quota,
+                                        wal_dir=args.wal_dir, mesh=mesh,
+                                        profile_dir=args.profile_dir,
+                                        adopt=adopt)
+        finally:
+            if adopt is not None:
+                adopt.close()
         print(f"served {served} responses", file=sys.stderr)
         return
 
@@ -326,26 +323,6 @@ def main() -> None:
         return
 
     g = parse_graph(args.graph)
-
-    if args.serve:
-        import sys
-
-        from ..api import EstimateConfig, Session, serve_loop
-        cfg = EstimateConfig(chunk=args.chunk, seed=args.seed,
-                             checkpoint_every=args.checkpoint_every,
-                             coalesce_window_s=args.coalesce_window,
-                             coalesce_max_requests=args.coalesce_max,
-                             sampler_backend=args.sampler_backend,
-                             depsum_backend=args.depsum_backend)
-        session = Session(g, cfg, mesh=mesh)
-        # stdout is the response stream — logs go to stderr
-        print(f"serving graph n={g.n} m={g.m} span={g.time_span}  "
-              f"mesh={mesh.shape if mesh is not None else None}  "
-              f"window={args.coalesce_window}s max={args.coalesce_max}",
-              file=sys.stderr, flush=True)
-        served = serve_loop(session, profile_dir=args.profile_dir)
-        print(f"served {served} requests", file=sys.stderr)
-        return
 
     # an inline DSL motif contains commas itself — treat a --motif that
     # parses as ONE spec as a single motif, not a comma list

@@ -47,7 +47,7 @@ provably never select), and ``Weights`` carries the real window count
 ``q`` as a *traced* scalar over bucket-shaped window arrays — epochs
 sharing buckets re-hit every compiled program.  The serve loop exposes
 all of this over NDJSON (``{"cmd": "ingest" | "advance" | "subscribe"}``,
-see ``repro.api.serve``), and ``launch/estimate.py --serve --stream``
+see ``repro.gateway``), and ``launch/estimate.py --serve --stream``
 runs it as a resident process.
 
 **Epoch determinism contract**: each standing query's count at epoch

@@ -1,6 +1,6 @@
 """Session-based public API (repro.api): coalescing windows, adaptive
-budgets, streaming, the serve loop, the motif DSL — and the shim
-contract.
+budgets, streaming, serving a session through the gateway loop, the
+motif DSL — and the shim contract.
 
 The load-bearing assertions:
 
@@ -25,11 +25,12 @@ from pathlib import Path
 
 import pytest
 
-from repro.api import EstimateConfig, Request, Session, serve_loop
+from repro.api import EstimateConfig, Request, Session
 from repro.core import engine
 from repro.core.estimator import estimate
 from repro.core.motif import (TemporalMotif, get_motif, is_motif_spec,
                               motif_spec, parse_motif_spec)
+from repro.gateway import gateway_serve_loop
 from repro.graphs import powerlaw_temporal_graph
 
 DELTA = 3_000
@@ -298,8 +299,19 @@ def test_motif_dsl_estimates_match_catalog(graph):
 
 
 # ---------------------------------------------------------------------------
-# serve loop (in-process; scripts/ci.sh smoke-tests the real subprocess)
+# a session served through the gateway loop as its default tenant
+# (in-process; scripts/ci.sh smoke-tests the real subprocess)
 # ---------------------------------------------------------------------------
+def _serve(session, lines) -> tuple[int, list[dict]]:
+    """Serve ``lines`` through the gateway loop with ``session`` adopted
+    as the default tenant; returns (served, parsed response lines)."""
+    out = io.StringIO()
+    served = gateway_serve_loop(
+        adopt=session, infile=io.StringIO("\n".join(lines) + "\n"),
+        outfile=out)
+    return served, [json.loads(ln) for ln in out.getvalue().splitlines()]
+
+
 def test_serve_loop_roundtrip(graph):
     lines = [
         json.dumps(dict(id=1, motif="M5-3", delta=DELTA, k=1024)),
@@ -310,21 +322,24 @@ def test_serve_loop_roundtrip(graph):
         json.dumps(dict(cmd="stats")),
         json.dumps(dict(cmd="quit")),
     ]
-    out = io.StringIO()
     with Session(graph, _cfg()) as s:
-        served = serve_loop(s, io.StringIO("\n".join(lines) + "\n"), out)
-    resp = [json.loads(ln) for ln in out.getvalue().splitlines()]
+        served, resp = _serve(s, lines)
+        # the loop leaves an adopted session open: its counters after
+        # the quit barrier are the whole run's
+        assert s.stats.completed == 3 and s.stats.submitted == 3
     by_id = {r["id"]: r for r in resp if "id" in r}
     assert served == 3
     want = GOLDEN[("M5-3", DELTA, 1024, 0)]
     assert by_id[1]["ok"] and by_id[1]["estimate"] == want["estimate"]
     assert by_id[1]["valid"] == want["valid"]
+    assert by_id[1]["tenant"] == "default"
     assert by_id[2]["ok"] and by_id[2]["motif"] == "0-1,1-2,2-0"
     assert not by_id[3]["ok"] and "no-such" in by_id[3]["error"]
     assert by_id[4]["ok"] and by_id[4]["k"] > 512   # adaptive growth ran
     assert by_id[4]["rse"] <= 0.2
+    # stats answers inline, without waiting on the dispatcher
     stats = next(r for r in resp if r.get("cmd") == "stats")
-    assert stats["completed"] == 3 and stats["submitted"] == 3
+    assert stats["ok"] and stats["tenants"]["default"]["mode"] == "graph"
     quit_r = next(r for r in resp if r.get("cmd") == "quit")
     assert quit_r["served"] == 3
 
@@ -335,10 +350,8 @@ def test_serve_loop_malformed_json_keeps_serving(graph):
     lines = ["{nope", "", json.dumps(dict(id=7, motif="M5-3", delta=DELTA,
                                           k=0)),
              json.dumps(dict(motif="M5-3", delta=DELTA, k=512))]
-    out = io.StringIO()
     with Session(graph, _cfg()) as s:
-        served = serve_loop(s, io.StringIO("\n".join(lines) + "\n"), out)
-    resp = [json.loads(ln) for ln in out.getvalue().splitlines()]
+        served, resp = _serve(s, lines)
     assert served == 1
     assert not resp[0]["ok"] and "bad json" in resp[0]["error"]
     assert not resp[1]["ok"] and resp[1]["id"] == 7      # k=0 rejected
@@ -352,10 +365,8 @@ def test_serve_loop_rejects_unknown_fields(graph):
     lines = [json.dumps(dict(id=1, motif="M5-3", delta=DELTA, k=512,
                              checkpoint="/tmp/evil.ckpt")),
              json.dumps(dict(id=2, motif="M5-3", delta=DELTA, k=512))]
-    out = io.StringIO()
     with Session(graph, _cfg()) as s:
-        served = serve_loop(s, io.StringIO("\n".join(lines) + "\n"), out)
-    resp = [json.loads(ln) for ln in out.getvalue().splitlines()]
+        served, resp = _serve(s, lines)
     assert served == 1
     assert not resp[0]["ok"] and "checkpoint" in resp[0]["error"]
     assert resp[1]["ok"]
@@ -365,8 +376,8 @@ def test_drain_failure_marks_window_mates_and_session_survives(graph,
                                                                tmp_path):
     """An execution failure mid-drain fails every handle of the window
     with the cause (no bare assert), and the session keeps serving.
-    Through the serve loop the same failure answers each request with a
-    structured ``error_kind`` and the SERVER also stays up."""
+    Through the gateway loop the same failure answers each request with
+    a structured ``error_kind`` and the SERVER also stays up."""
     s = Session(graph, _cfg())
     good = s.submit(Request("M5-3", DELTA, 512, seed=0))
     bad = s.submit(Request("M5-3", DELTA, 512, seed=1,
@@ -381,30 +392,31 @@ def test_drain_failure_marks_window_mates_and_session_survives(graph,
     r = s.submit(Request("M5-3", DELTA, 1024, seed=0)).result()
     assert r.cnt2_sum == GOLDEN[("M5-3", DELTA, 1024, 0)]["cnt2"]
 
-    # serve-loop level: a fatally failing first drain answers ok:false
-    # with the taxonomy kind, then the SAME server process answers the
-    # next request (and a health probe) normally
+    # serve level: a fatally failing drain answers ok:false with the
+    # taxonomy kind, then the SAME adopted session answers the next
+    # request (and a health probe) normally.  The second request runs
+    # through a second loop so the gateway cannot fuse it into the
+    # failing window (a failed drain marks every window-mate).
     from repro.resilience import FatalError, FaultInjector, FaultSpec
     from repro.resilience.retry import STATS as RSTATS
-    lines = [json.dumps(dict(id=1, motif="M5-3", delta=DELTA, k=512)),
-             json.dumps(dict(cmd="health")),    # answered WITHOUT draining
-             json.dumps(dict(cmd="stats")),     # forces the failing drain
-             json.dumps(dict(id=2, motif="M5-3", delta=DELTA, k=1024)),
-             json.dumps(dict(cmd="quit"))]
-    out = io.StringIO()
     drain_failures0 = RSTATS.drain_failures
     with FaultInjector([FaultSpec("engine.dispatch", hits=(0,),
                                   exc=FatalError)]):
-        served = serve_loop(s, io.StringIO("\n".join(lines) + "\n"), out)
-    resp = [json.loads(ln) for ln in out.getvalue().splitlines()]
-    by_id = {r["id"]: r for r in resp if "id" in r}
-    assert served == 2
+        served1, resp = _serve(s, [
+            json.dumps(dict(id=1, motif="M5-3", delta=DELTA, k=512)),
+            json.dumps(dict(cmd="health")),   # answered WITHOUT draining
+            json.dumps(dict(cmd="quit"))])
+        served2, resp2 = _serve(s, [
+            json.dumps(dict(id=2, motif="M5-3", delta=DELTA, k=1024)),
+            json.dumps(dict(cmd="quit"))])
+    by_id = {r["id"]: r for r in resp + resp2 if "id" in r}
+    assert served1 == 0 and served2 == 1     # a failed handle is no serve
     assert not by_id[1]["ok"] and by_id[1]["error_kind"] == "fatal"
     assert by_id[2]["ok"]
     assert by_id[2]["valid"] == GOLDEN[("M5-3", DELTA, 1024, 0)]["valid"]
     health = next(r for r in resp if r.get("cmd") == "health")
-    assert health["ok"] and health["mode"] == "plain"
-    assert health["pending"] == 1           # probed mid-window, no drain
+    assert health["ok"] and health["mode"] == "gateway"
+    assert health["tenants"]["default"]["mode"] == "graph"
     assert "resilience" in health
     assert RSTATS.drain_failures == drain_failures0 + 1
     s.close()

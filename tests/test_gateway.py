@@ -175,6 +175,26 @@ def test_idle_lru_eviction_and_reopen():
     assert not state.tenants
 
 
+def test_adopted_session_follows_pool_rules_and_stays_open():
+    """An adopted session pools like any tenant (capacity, idle-LRU
+    eviction, duplicate names) but leaving the pool never closes it."""
+    state = GatewayState(_cfg(), max_tenants=1)
+    s = Session(_graph("er:n=40,m=400,time_span=9000,seed=1"), _cfg())
+    t = state.adopt("default", s)
+    assert t.mode == "graph" and t.cur_session() is s and not t.owned
+    with pytest.raises(ValueError, match="already open"):
+        state.adopt("default", s)
+    with pytest.raises(TypeError):
+        state.adopt("other", object())
+    # at capacity the adopted (idle) tenant is the eviction victim
+    state.open_tenant("b", graph="er:n=40,m=400,time_span=9000,seed=2")
+    assert set(state.tenants) == {"b"} and state.evictions == 1
+    state.close_all()
+    r = s.submit(Request("M4-2", DELTA, 256)).result()   # still open
+    assert r.k == 256
+    s.close()
+
+
 def test_tenant_name_and_spec_validation(tmp_path):
     state = GatewayState(_cfg(), max_tenants=2)
     for bad in ("", "../etc", "a/b", ".hidden", "x" * 65, 7, None):

@@ -23,7 +23,7 @@ import json
 import pytest
 
 from repro import obs
-from repro.api import EstimateConfig, Request, Session, serve_loop
+from repro.api import EstimateConfig, Request, Session
 from repro.core import engine
 from repro.core.batch import estimate_many
 from repro.core.estimator import estimate
@@ -276,16 +276,21 @@ def test_no_retrace_warm_path_with_tracing(no_retrace):
 def test_serve_metrics_and_trace_verbs():
     obs.set_level("trace")
     obs.RECORDER.clear()
-    lines = [json.dumps({"id": 1, "motif": "M4-2", "delta": DELTA,
-                         "k": 256}),
-             '{"cmd": "stats"}',        # forces the drain before scraping
-             '{"cmd": "metrics"}', '{"cmd": "trace"}',
-             '{"cmd": "profile", "windows": 1}', '{"cmd": "health"}',
-             '{"cmd": "quit"}']
+    # the telemetry verbs answer inline, so the request's drain must have
+    # finished before they are read: serve it in a first loop, whose quit
+    # waits on the dispatcher, and scrape in a second loop
+    scrape = ['{"cmd": "metrics"}', '{"cmd": "trace"}',
+              '{"cmd": "profile", "windows": 1}', '{"cmd": "health"}',
+              '{"cmd": "quit"}']
     out = io.StringIO()
-    serve_loop(Session(_graph(), _cfg()),
-               infile=io.StringIO("\n".join(lines) + "\n"), outfile=out)
+    with Session(_graph(), _cfg()) as s:
+        for lines in ([json.dumps({"id": 1, "motif": "M4-2",
+                                   "delta": DELTA, "k": 256}),
+                       '{"cmd": "quit"}'], scrape):
+            gateway_serve_loop(adopt=s, outfile=out,
+                               infile=io.StringIO("\n".join(lines) + "\n"))
     resp = [json.loads(ln) for ln in out.getvalue().splitlines()]
+    assert next(r for r in resp if r.get("id") == 1)["ok"]
     met = next(r for r in resp if r.get("cmd") == "metrics")
     assert met["ok"] and met["content_type"].startswith("text/plain")
     assert "# TYPE repro_engine_dispatches_total counter" in met["text"]
@@ -293,7 +298,7 @@ def test_serve_metrics_and_trace_verbs():
     tr = next(r for r in resp if r.get("cmd") == "trace")
     assert tr["ok"] and tr["level"] == "trace" and tr["count"] == len(
         tr["spans"]) > 0
-    assert {"serve.intake", "session.drain", "engine.dispatch"} \
+    assert {"gateway.intake", "session.drain", "engine.dispatch"} \
         <= {s["name"] for s in tr["spans"]}
     prof = next(r for r in resp if r.get("cmd") == "profile")
     assert prof["ok"] is False          # no --profile-dir configured

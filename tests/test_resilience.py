@@ -16,9 +16,10 @@ import os
 import numpy as np
 import pytest
 
-from repro.api import EstimateConfig, Request, Session, serve_loop
+from repro.api import EstimateConfig, Request, Session
 from repro.core.estimator import estimate
 from repro.core.motif import get_motif
+from repro.gateway import gateway_serve_loop
 from repro.graphs import powerlaw_temporal_graph
 from repro.resilience import (BadRequestError, FatalError, FaultInjector,
                               FaultSpec, TransientError, atomic_write_json,
@@ -468,14 +469,22 @@ def test_torn_checkpoint_file_treated_as_absent(graph, tmp_path):
 # ---------------------------------------------------------------------------
 # serve loop: wire-level degradation + health
 # ---------------------------------------------------------------------------
+def _serve(resident, lines) -> tuple[int, list[dict]]:
+    """Serve ``lines`` through the gateway loop with ``resident`` (a
+    Session or StreamingSession) adopted as the default tenant."""
+    out = io.StringIO()
+    served = gateway_serve_loop(
+        adopt=resident, infile=io.StringIO("\n".join(lines) + "\n"),
+        outfile=out)
+    return served, [json.loads(ln) for ln in out.getvalue().splitlines()]
+
+
 def test_serve_deadline_ms_degraded_partial(graph):
     lines = [json.dumps(dict(id=1, motif="M5-3", delta=DELTA, k=1 << 17,
                              deadline_ms=150)),
              json.dumps(dict(cmd="quit"))]
-    out = io.StringIO()
     with Session(graph, _cfg()) as s:
-        serve_loop(s, io.StringIO("\n".join(lines) + "\n"), out)
-    resp = [json.loads(ln) for ln in out.getvalue().splitlines()]
+        _, resp = _serve(s, lines)
     r = next(x for x in resp if x.get("id") == 1)
     assert r["ok"] and r["degraded"]         # degraded partial, NOT error
     assert r["k_done"] == r["k"] and r["k"] % CHUNK == 0
@@ -485,47 +494,47 @@ def test_serve_deadline_ms_degraded_partial(graph):
 def test_serve_stream_health_reports_wal_and_epoch(tmp_path):
     store = StreamStore.recover(str(tmp_path / "h.wal"), horizon=None)
     cfg = EstimateConfig(chunk=CHUNK, seed=0)
-    lines = [
-        json.dumps(dict(cmd="health")),
-        json.dumps(dict(cmd="subscribe", motif="0-1,1-2,2-0", delta=400,
-                        k=512)),
-        json.dumps(dict(cmd="ingest",
-                        edges=[[i % 7, (i + 1) % 7, 100 * i]
-                               for i in range(40)])),
-        json.dumps(dict(cmd="advance")),
-        json.dumps(dict(cmd="health")),
-        json.dumps(dict(cmd="quit")),
-    ]
-    out = io.StringIO()
+    # health answers inline: the second probe runs in a second loop, so
+    # the first loop's quit has waited for the advance to finish
     with StreamingSession(store=store, config=cfg) as ss:
-        serve_loop(None, io.StringIO("\n".join(lines) + "\n"), out,
-                   stream=ss)
-    resp = [json.loads(ln) for ln in out.getvalue().splitlines()]
-    health = [r for r in resp if r.get("cmd") == "health"]
+        _, resp = _serve(ss, [
+            json.dumps(dict(cmd="health")),
+            json.dumps(dict(cmd="subscribe", motif="0-1,1-2,2-0",
+                            delta=400, k=512)),
+            json.dumps(dict(cmd="ingest",
+                            edges=[[i % 7, (i + 1) % 7, 100 * i]
+                                   for i in range(40)])),
+            json.dumps(dict(cmd="advance")),
+            json.dumps(dict(cmd="quit"))])
+        _, resp2 = _serve(ss, [json.dumps(dict(cmd="health")),
+                               json.dumps(dict(cmd="quit"))])
+    health = [r for r in resp + resp2 if r.get("cmd") == "health"]
     assert len(health) == 2
-    assert health[0]["mode"] == "stream" and health[0]["epoch"] == 0
-    assert health[0]["wal"]["records"] == 0
-    assert health[1]["epoch"] == 1
-    assert health[1]["wal"]["records"] == 2      # ingest + advance logged
-    assert health[1]["wal"]["offset"] > health[0]["wal"]["offset"]
+    tenant = [h["tenants"]["default"] for h in health]
+    assert tenant[0]["mode"] == "stream" and tenant[0]["epoch"] == 0
+    assert tenant[0]["wal"]["records"] == 0
+    assert tenant[1]["epoch"] == 1
+    assert tenant[1]["wal"]["records"] == 2      # ingest + advance logged
+    assert tenant[1]["wal"]["offset"] > tenant[0]["wal"]["offset"]
     assert health[1]["resilience"]["wal_records"] >= 2
     adv = next(r for r in resp if r.get("cmd") == "advance")
     assert adv["ok"] and adv["queries"] == 1
 
 
 def test_serve_write_fault_counted_server_survives(graph):
-    lines = [json.dumps(dict(id=1, motif="M5-3", delta=DELTA, k=512)),
-             json.dumps(dict(cmd="stats")),
+    # stats answers inline at intake, before the request is even queued,
+    # so it is the first write and the one the fault takes
+    lines = [json.dumps(dict(cmd="stats")),
+             json.dumps(dict(id=1, motif="M5-3", delta=DELTA, k=512)),
              json.dumps(dict(cmd="quit"))]
-    out = io.StringIO()
     e0 = RSTATS.emit_failures
     with Session(graph, _cfg()) as s:
         with FaultInjector([FaultSpec("serve.write", hits=(0,))]):
-            served = serve_loop(s, io.StringIO("\n".join(lines) + "\n"), out)
+            served, resp = _serve(s, lines)
     assert served == 1
     assert RSTATS.emit_failures == e0 + 1
-    resp = [json.loads(ln) for ln in out.getvalue().splitlines()]
     # the first response was lost to the injected write fault, but the
-    # server kept serving: stats + quit still answered
-    assert any(r.get("cmd") == "stats" for r in resp)
+    # server kept serving: the request + quit still answered
+    assert not any(r.get("cmd") == "stats" for r in resp)
+    assert next(r for r in resp if r.get("id") == 1)["ok"]
     assert any(r.get("cmd") == "quit" for r in resp)

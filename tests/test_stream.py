@@ -24,11 +24,12 @@ import json
 import numpy as np
 import pytest
 
-from repro.api import EstimateConfig, Request, serve_loop
+from repro.api import EstimateConfig, Request
 from repro.core import engine
 from repro.core.estimator import estimate
 from repro.core.graph import pad_bucket, pad_snapshot
 from repro.core.motif import get_motif
+from repro.gateway import gateway_serve_loop
 from repro.graphs import powerlaw_temporal_graph
 from repro.graphs.loader import iter_edge_batches, load_edge_list
 from repro.stream import (StandingQuery, StreamingSession, StreamStore,
@@ -265,14 +266,20 @@ def test_load_edge_list_gz_and_replay_roundtrip(tmp_path, graph):
 # ---------------------------------------------------------------------------
 # serve loop: ingest / advance / subscribe round trip
 # ---------------------------------------------------------------------------
-def _run_stream_serve(lines, **ss_kw):
+def _run_stream_serve(*phases, **ss_kw):
+    """Serve each phase (a list of wire objects) through its own gateway
+    loop over ONE adopted stream; a phase's quit/EOF waits on the
+    dispatcher, so an inline probe opening the next phase sees it done.
+    Returns (served summed, every phase's response lines in order)."""
     out = io.StringIO()
     kw = dict(config=_cfg(), horizon=10_000, min_m_bucket=64)
     kw.update(ss_kw)
+    served = 0
     with StreamingSession(**kw) as ss:
-        served = serve_loop(
-            None, io.StringIO("\n".join(json.dumps(o) for o in lines) + "\n"),
-            out, stream=ss)
+        for lines in phases:
+            served += gateway_serve_loop(
+                adopt=ss, outfile=out, infile=io.StringIO(
+                    "\n".join(json.dumps(o) for o in lines) + "\n"))
     return served, [json.loads(ln) for ln in out.getvalue().splitlines()]
 
 
@@ -287,16 +294,17 @@ def test_serve_stream_roundtrip():
         {"id": 5, "motif": "0-1,1-2", "delta": 400, "k": 128},
         {"cmd": "ingest", "edges": edges2},
         {"cmd": "advance"},
-        {"cmd": "stats"},
-        {"cmd": "unsubscribe", "sub": 0},
         {"cmd": "quit"},
+    ], [
+        {"cmd": "stats"},                 # inline: after phase 1's quit
+        {"cmd": "unsubscribe", "sub": 0},
     ])
-    # the ad-hoc request coalesces (window_s=60) and drains at the next
-    # advance, so its response lands after the second ingest's
-    sub, bad_adv, ing1, ep0_q, ep0, ing2, adhoc, ep1_q, ep1, stats, unsub, \
-        quit_r = rs
-    assert sub == {"ok": True, "cmd": "subscribe", "sub": 0,
-                   "name": "0-1,1-2"}
+    # the tenant's queue is FIFO: the ad-hoc request drains as its own
+    # window before the second ingest executes
+    sub, bad_adv, ing1, ep0_q, ep0, adhoc, ing2, ep1_q, ep1, quit_r, \
+        stats, unsub = rs
+    assert sub == {"ok": True, "cmd": "subscribe", "tenant": "default",
+                   "sub": 0, "name": "0-1,1-2"}
     assert not bad_adv["ok"] and "empty stream" in bad_adv["error"]
     assert ing1["ok"] and ing1["ingested"] == 80 and ing1["buffered"] == 80
     assert ep0_q["ok"] and ep0_q["sub"] == 0 and ep0_q["epoch"] == 0
@@ -309,7 +317,8 @@ def test_serve_stream_roundtrip():
     assert adhoc["estimate"] == ep0_q["estimate"]
     assert ep1_q["epoch"] == 1 and ep1["epoch"] == 1
     assert ep1["evicted"] > 0                     # horizon aged epoch-0 edges
-    assert stats["epochs"] == 2 and stats["subscriptions"] == 1
+    tenant = stats["tenants"]["default"]
+    assert tenant["epoch"] == 2 and tenant["subscriptions"] == 1
     assert unsub["ok"] and unsub["sub"] == 0
     assert quit_r["ok"]
     # 1 ad-hoc + 2 standing-epoch responses
@@ -337,11 +346,16 @@ def test_serve_plain_session_rejects_stream_cmds(graph):
     from repro.api import Session
     out = io.StringIO()
     with Session(graph, _cfg()) as s:
-        serve_loop(s, io.StringIO('{"cmd": "advance"}\n'), out)
+        gateway_serve_loop(adopt=s, infile=io.StringIO('{"cmd": "advance"}\n'),
+                           outfile=out)
     r = json.loads(out.getvalue().splitlines()[0])
-    assert not r["ok"] and "stream mode" in r["error"]
-    with pytest.raises(ValueError):
-        serve_loop(None)
+    assert not r["ok"] and "needs a stream tenant" in r["error"]
+    # with no adopted session, a tenant-less line has nowhere to go
+    out = io.StringIO()
+    gateway_serve_loop(infile=io.StringIO('{"cmd": "advance"}\n'),
+                       outfile=out)
+    r = json.loads(out.getvalue().splitlines()[0])
+    assert not r["ok"] and 'need "tenant"' in r["error"]
 
 
 # ---------------------------------------------------------------------------
